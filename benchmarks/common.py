@@ -12,6 +12,8 @@ changes.
 """
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,8 +23,16 @@ from repro.configs.tm_iris import TMSystemParams
 from repro.core import init_runtime, init_state
 from repro.core import manager as mgr
 from repro.data import blocks
+from repro.launch import compile_cache
 
 CFG = TM_SYS.tm
+
+
+def enable_compile_cache() -> str:
+    """JAX's persistent compile cache for a benchmark run: the checkout's
+    fixed ``.jax_cache/`` unless ``JAX_COMPILATION_CACHE_DIR`` is set."""
+    return compile_cache.enable(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
 
 def _dataset(dataset: str):

@@ -252,4 +252,5 @@ def main(n_orderings: int = 24):
 
 
 if __name__ == "__main__":
+    common.enable_compile_cache()
     main(int(os.environ.get("REPRO_BENCH_ORDERINGS", "24")))

@@ -152,4 +152,5 @@ def main():
 
 
 if __name__ == "__main__":
+    common.enable_compile_cache()
     main()

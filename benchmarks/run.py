@@ -44,4 +44,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from benchmarks import common
+
+    common.enable_compile_cache()
     main()

@@ -138,4 +138,5 @@ def main():
 
 
 if __name__ == "__main__":
+    common.enable_compile_cache()
     main()

@@ -224,4 +224,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from benchmarks import common
+
+    common.enable_compile_cache()
     main()

@@ -48,7 +48,7 @@ class TMConfig:
     n_states: int = 99               # N states per action (TA has 2N states)
     s_policy: str = "standard"       # "standard" | "hardware"  (see DESIGN.md §2)
     boost_true_positive: bool = True # deterministic strengthen on (clause=1,lit=1)
-    backend: str = "ref"             # kernel backend name (see kernels/dispatch.py)
+    backend: str = "auto"            # kernel backend name (see kernels/dispatch.py)
 
     def __post_init__(self):
         if self.max_clauses % 2:

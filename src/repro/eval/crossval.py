@@ -30,6 +30,7 @@ figure benchmarks are thin callers of.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -195,6 +196,13 @@ class CrossValRun:
     cfg: TMConfig
     mesh: Optional[Mesh] = None
 
+    def _on_mesh(self):
+        """The mesh as JAX's context mesh around a device program, so the
+        Pallas kernels run per device under ``shard_map`` (kernels/ops.py)."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
     def _put(self, tree, n_replicas: Optional[int] = None):
         if self.mesh is None:
             return tree
@@ -237,7 +245,9 @@ class CrossValRun:
         )
 
         t0 = time.perf_counter()
-        acc = _sweep_device(self.cfg, s_rep, T_rep, off, val, n_epochs, keys)
+        with self._on_mesh():
+            acc = _sweep_device(self.cfg, s_rep, T_rep, off, val, n_epochs,
+                                keys)
         acc = jax.block_until_ready(acc)
         wall = time.perf_counter() - t0
 
@@ -269,9 +279,10 @@ class CrossValRun:
         O = keys.shape[0]
         states, sets, keys = self._put((states, sets, keys), n_replicas=O)
         t0 = time.perf_counter()
-        state, accs, activity = _system_device(
-            self.cfg, sys_cfg, states, rt, sets, schedule, keys
-        )
+        with self._on_mesh():
+            state, accs, activity = _system_device(
+                self.cfg, sys_cfg, states, rt, sets, schedule, keys
+            )
         accs = jax.block_until_ready(accs)
         return SystemResult(
             state=state,

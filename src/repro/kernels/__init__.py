@@ -10,6 +10,7 @@ clause_eval.py — clause evaluation as an int8 MXU matmul (the paper's
 packing.py     — the bit-packed literal layout: uint32 words, two-half
                  [pack(x), pack(~x)] literal split, tail-bit contract
 feedback.py    — fused Type I/II TA-bank update (one VPU pass per datapoint)
-ops.py         — jit'd public wrappers (interpret=True on CPU; TPU target)
+ops.py         — public wrappers; dispatch binds interpret mode from the
+                 platform, and on a mesh each kernel runs under shard_map
 ref.py         — pure-jnp oracles; kernels are asserted bit-exact vs these
 """

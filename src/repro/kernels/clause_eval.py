@@ -79,7 +79,7 @@ def clause_counts(
     include: jax.Array,   # [CJ, L] int8/bool — flattened (class, clause) rows
     literals: jax.Array,  # [L] bool
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(violations [CJ] i32, n_included [CJ] i32) via one MXU matmul."""
     cj, L = include.shape
@@ -113,7 +113,7 @@ def clause_eval(
     literals: jax.Array,  # [L] bool
     *,
     training: bool,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel-backed clause outputs [C, J] bool (same contract as ref)."""
     C, J, L = include.shape
@@ -131,7 +131,7 @@ def clause_counts_batch(
     include: jax.Array,   # [CJ, L] int8/bool — flattened (class, clause) rows
     literals: jax.Array,  # [B, L] bool — one row per datapoint
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(violations [CJ, B] i32, n_included [CJ] i32) via ONE MXU matmul.
 
@@ -176,7 +176,7 @@ def clause_eval_batch(
     literals: jax.Array,  # [B, L] bool
     *,
     training: bool,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel-backed batch-first clause outputs [B, C, J] bool."""
     C, J, L = include.shape
@@ -194,7 +194,7 @@ def clause_counts_replicated(
     include: jax.Array,   # [R, CJ, L] int8/bool — per-replica include banks
     literals: jax.Array,  # [D, L] bool — replica r reads row r % D
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(violations [R, CJ] i32, n_included [R, CJ] i32) in ONE kernel launch.
 
@@ -237,7 +237,7 @@ def clause_eval_replicated(
     literals: jax.Array,  # [D, L] bool — replica r reads row r % D
     *,
     training: bool,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel-backed replica-first clause outputs [R, C, J] bool."""
     R, C, J, L = include.shape
@@ -255,7 +255,7 @@ def clause_counts_batch_replicated(
     include: jax.Array,   # [R, CJ, L] int8/bool — per-replica include banks
     literals: jax.Array,  # [D, B, L] bool — replica r reads batch r % D
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """(violations [R, CJ, B] i32, n_included [R, CJ] i32) in ONE launch.
 
@@ -309,7 +309,7 @@ def clause_eval_batch_replicated(
     literals: jax.Array,  # [D, B, L] bool — replica r reads batch r % D
     *,
     training: bool,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel-backed replica-first batch clause outputs [R, B, C, J] bool.
 
@@ -392,7 +392,7 @@ def clause_counts_batch_packed(
     include_packed: jax.Array,   # [CJ, W] uint32 — packed include rows
     literals_packed: jax.Array,  # [B, W] uint32 — packed literal rows
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Violations [CJ, B] i32 via the word-tiled AND+popcount grid.
 
@@ -433,7 +433,7 @@ def clause_eval_batch_packed(
     literals_packed: jax.Array,  # [B, W] uint32
     *,
     training: bool,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel-backed packed batch clause outputs [B, C, J] bool.
 
@@ -455,7 +455,7 @@ def clause_counts_batch_replicated_packed(
     include_packed: jax.Array,   # [R, CJ, W] uint32
     literals_packed: jax.Array,  # [D, B, W] uint32 — replica r reads r % D
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Violations [R, CJ, B] i32 in ONE launch: the packed replica plane.
 
@@ -497,7 +497,7 @@ def clause_eval_batch_replicated_packed(
     literals_packed: jax.Array,  # [D, B, W] uint32 — replica r reads r % D
     *,
     training: bool,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Kernel-backed packed replica-first batch outputs [R, B, C, J] bool."""
     R, C, J, W = include_packed.shape
@@ -523,7 +523,7 @@ def clause_eval_batch_replicated_packed(
 
 def clause_eval_batch_pruned(
     include: jax.Array, sel: jax.Array, literals: jax.Array,
-    *, training: bool, interpret: bool = True,
+    *, training: bool, interpret: bool,
 ) -> jax.Array:
     """[C, J, L] x sel [C, M] x [B, L] -> [B, C, M] (see ref twin)."""
     return clause_eval_batch(
@@ -534,7 +534,7 @@ def clause_eval_batch_pruned(
 
 def clause_eval_batch_pruned_replicated(
     include: jax.Array, sel: jax.Array, literals: jax.Array,
-    *, training: bool, interpret: bool = True,
+    *, training: bool, interpret: bool,
 ) -> jax.Array:
     """[R, C, J, L] x sel [R, C, M] x [D, B, L] -> [R, B, C, M]."""
     return clause_eval_batch_replicated(
@@ -545,7 +545,7 @@ def clause_eval_batch_pruned_replicated(
 
 def clause_eval_batch_pruned_packed(
     include_packed: jax.Array, sel: jax.Array, literals_packed: jax.Array,
-    *, training: bool, interpret: bool = True,
+    *, training: bool, interpret: bool,
 ) -> jax.Array:
     """[C, J, W] u32 x sel [C, M] x [B, W] u32 -> [B, C, M]."""
     return clause_eval_batch_packed(
@@ -556,7 +556,7 @@ def clause_eval_batch_pruned_packed(
 
 def clause_eval_batch_pruned_replicated_packed(
     include_packed: jax.Array, sel: jax.Array, literals_packed: jax.Array,
-    *, training: bool, interpret: bool = True,
+    *, training: bool, interpret: bool,
 ) -> jax.Array:
     """[R, C, J, W] u32 x sel [R, C, M] x [D, B, W] u32 -> [R, B, C, M]."""
     return clause_eval_batch_replicated_packed(

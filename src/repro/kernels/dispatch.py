@@ -7,9 +7,11 @@ implementation is chosen at trace time through a registry keyed by
 * ``"ref"``    — pure-jnp oracles (:mod:`repro.kernels.ref`). CPU default and
   the semantic ground truth every other backend is asserted bit-exact against.
 * ``"pallas"`` — TPU Pallas kernels (:mod:`repro.kernels.ops`): MXU clause
-  matmul + fused VPU feedback plane (interpreted off-TPU).
+  matmul + fused VPU feedback plane. :func:`resolve` decides interpret mode
+  here, once, from the platform: compiled Mosaic kernels on a TPU, the
+  Pallas interpreter everywhere else.
 * ``"auto"``   — resolves to ``pallas`` when JAX is running on a TPU,
-  ``ref`` otherwise.
+  ``ref`` otherwise (off the chip, ``TM_BACKEND`` may name another backend).
 
 Every backend implements the same typed contract, :class:`KernelBackend`,
 and the contract is **batch-first**: ``clause_eval_batch`` takes ``[B, L]``
@@ -24,6 +26,7 @@ backs which name; ``if cfg.backend == ...`` branches anywhere else are a bug.
 from __future__ import annotations
 
 import os
+from functools import partial
 from typing import Callable, NamedTuple
 
 import jax
@@ -97,6 +100,7 @@ class KernelBackend(NamedTuple):
     """
 
     name: str
+    interpret: bool  # pallas: kernels run in the Pallas interpreter
     clause_eval: Callable[..., jax.Array]
     clause_eval_batch: Callable[..., jax.Array]
     clause_eval_replicated: Callable[..., jax.Array]
@@ -110,6 +114,9 @@ class KernelBackend(NamedTuple):
     feedback_step: Callable[..., jax.Array]
     feedback_step_replicated: Callable[..., jax.Array]
 
+
+# The 12 kernel entries of the contract (every field after name/interpret).
+ENTRIES: tuple[str, ...] = KernelBackend._fields[2:]
 
 # Factories, not instances: "pallas" must not import Pallas machinery unless
 # it is actually selected (keeps ref-only environments import-light).
@@ -129,12 +136,11 @@ def available() -> tuple[str, ...]:
 
 
 def _auto_name() -> str:
-    # TM_BACKEND overrides auto-resolution (CI runs the kernel/parity suite
-    # a second time with TM_BACKEND=pallas in interpret mode).
-    env = os.environ.get("TM_BACKEND")
-    if env:
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    if jax.default_backend() == "tpu":
+        return "pallas"
+    # Off the chip TM_BACKEND may re-route auto (CI runs the kernel/parity
+    # suites a second time with TM_BACKEND=pallas, interpreted).
+    return os.environ.get("TM_BACKEND") or "ref"
 
 
 def resolve(name: str) -> KernelBackend:
@@ -154,51 +160,20 @@ def _make_ref() -> KernelBackend:
     from repro.kernels import ref
 
     return KernelBackend(
-        name="ref",
-        clause_eval=ref.clause_eval,
-        clause_eval_batch=ref.clause_eval_batch,
-        clause_eval_replicated=ref.clause_eval_replicated,
-        clause_eval_batch_replicated=ref.clause_eval_batch_replicated,
-        clause_eval_batch_packed=ref.clause_eval_batch_packed,
-        clause_eval_batch_replicated_packed=(
-            ref.clause_eval_batch_replicated_packed
-        ),
-        clause_eval_batch_pruned=ref.clause_eval_batch_pruned,
-        clause_eval_batch_pruned_replicated=(
-            ref.clause_eval_batch_pruned_replicated
-        ),
-        clause_eval_batch_pruned_packed=ref.clause_eval_batch_pruned_packed,
-        clause_eval_batch_pruned_replicated_packed=(
-            ref.clause_eval_batch_pruned_replicated_packed
-        ),
-        feedback_step=ref.feedback_step,
-        feedback_step_replicated=ref.feedback_step_replicated,
+        name="ref", interpret=False,
+        **{e: getattr(ref, e) for e in ENTRIES},
     )
 
 
 def _make_pallas() -> KernelBackend:
     from repro.kernels import ops
 
+    # Compiled Mosaic kernels on the chip; off it the interpreter is the
+    # only way the kernels run.
+    interpret = jax.default_backend() != "tpu"
     return KernelBackend(
-        name="pallas",
-        clause_eval=ops.clause_eval,
-        clause_eval_batch=ops.clause_eval_batch,
-        clause_eval_replicated=ops.clause_eval_replicated,
-        clause_eval_batch_replicated=ops.clause_eval_batch_replicated,
-        clause_eval_batch_packed=ops.clause_eval_batch_packed,
-        clause_eval_batch_replicated_packed=(
-            ops.clause_eval_batch_replicated_packed
-        ),
-        clause_eval_batch_pruned=ops.clause_eval_batch_pruned,
-        clause_eval_batch_pruned_replicated=(
-            ops.clause_eval_batch_pruned_replicated
-        ),
-        clause_eval_batch_pruned_packed=ops.clause_eval_batch_pruned_packed,
-        clause_eval_batch_pruned_replicated_packed=(
-            ops.clause_eval_batch_pruned_replicated_packed
-        ),
-        feedback_step=ops.feedback_step,
-        feedback_step_replicated=ops.feedback_step_replicated,
+        name="pallas", interpret=interpret,
+        **{e: partial(getattr(ops, e), interpret=interpret) for e in ENTRIES},
     )
 
 

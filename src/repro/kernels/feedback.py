@@ -99,7 +99,7 @@ def feedback_plane(
     p_erase: jax.Array,       # scalar f32
     *,
     n_states: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused TA update over the flattened plane. Returns new ta_state [CJ, L]."""
     cj, L = ta_state.shape
@@ -153,7 +153,7 @@ def feedback_plane_replicated(
     p_erase: jax.Array,       # [R] f32
     *,
     n_states: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """R independent TA banks updated in ONE kernel launch.
 

@@ -1,4 +1,7 @@
 import os
+# A CPU-only tool: it forces virtual host devices and re-launches itself per
+# cell, so it must never take a TPU (a chip belongs to one process).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
 

@@ -13,14 +13,21 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    # The models shard through GSPMD (with_sharding_constraint hints), which
+    # needs Auto axes; jax.make_mesh defaults to Explicit ones.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False, n_pods: int = 2):
     shape = (n_pods, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

@@ -28,6 +28,7 @@ parity suite pins bitwise against the replicated path.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from functools import partial
@@ -461,6 +462,15 @@ class TMService:
             else tun_mod.TuneController(sc.tunable, K, cfg.max_clauses)
         )
 
+    @contextlib.contextmanager
+    def _device(self):
+        """The consumer-side critical section (DESIGN.md §14): the device
+        lock and, on a mesh, the mesh as JAX's context mesh — the Pallas
+        kernels then run per device under ``shard_map`` (kernels/ops.py)."""
+        with self._device_lock, (contextlib.nullcontext() if self.mesh is None
+                                 else jax.set_mesh(self.mesh)):
+            yield
+
     def _ingest(self, xs) -> jax.Array:
         """Bool rows -> the service's wire representation: bool features
         unpacked, uint32 words when ``sc.packed`` (already-packed uint32
@@ -482,7 +492,7 @@ class TMService:
         Under residency this is the ASSEMBLED full-K logical fleet (device
         slots gathered + spilled snapshots) — a read-only view; use
         save/restore or evict/activate to move state."""
-        with self._device_lock:
+        with self._device():
             self.flush()
             if self._res is None:
                 return self._ss
@@ -494,7 +504,7 @@ class TMService:
         """Replacing device state wholesale re-syncs the occupancy mirror
         (benchmarks pre-fill buffers this way). Traffic staged but never
         read back via the getter still lands on the next flush."""
-        with self._device_lock:
+        with self._device():
             if self._res is not None:
                 raise ValueError(
                     "a residency service's device plane cannot be "
@@ -574,7 +584,7 @@ class TMService:
         """
         K = self.n_replicas
         landed = np.zeros(K, dtype=np.int64)
-        with self._device_lock:
+        with self._device():
             while True:
                 with self.router.lock:
                     block = self.router.take_block()
@@ -859,7 +869,7 @@ class TMService:
         whole-fleet flush to spill a handful of members; other lanes'
         staged rows stay staged). Any later submit/serve/analysis
         touching the evicted members re-activates transparently."""
-        with self._device_lock:
+        with self._device():
             if self._res is None:
                 raise ValueError(
                     "service has no residency layer (resident is None)"
@@ -892,7 +902,7 @@ class TMService:
     def activate(self, replicas) -> np.ndarray:
         """Make the named replicas device-resident (at most ``resident``
         of them); returns their slots."""
-        with self._device_lock:
+        with self._device():
             if self._res is None:
                 raise ValueError(
                     "service has no residency layer (resident is None)"
@@ -936,7 +946,7 @@ class TMService:
         # the drain bodies keep the occupancy mirror in sync per chunk (not
         # here, after the fact) so an on_chunk callback raising mid-drain
         # can't desync accounting from the device
-        with self._device_lock:
+        with self._device():
             self.flush()
             if self._res is None:
                 return (self._drain_k1(budget, on_chunk) if self._k1
@@ -1052,7 +1062,7 @@ class TMService:
         :meth:`serve_replicas` to name the members a request targets.
         """
         xs = self._ingest(xs)
-        with self._device_lock:
+        with self._device():
             if self._res is not None:
                 raise ValueError(
                     "TMService.serve needs the whole fleet device-resident, "
@@ -1151,7 +1161,7 @@ class TMService:
             )
         tuner = self._require_tuner() if tunable else None
         outs, auxes = [], []
-        with self._device_lock:
+        with self._device():
             for i in range(0, len(rids), cap):
                 cohort = rids[i:i + cap]
                 slots = (cohort if self._res is None
@@ -1207,7 +1217,7 @@ class TMService:
         K = self.n_replicas
         C, J = self.cfg.max_classes, self.cfg.max_clauses
         scores = np.zeros((K, C, J), dtype=np.int32)
-        with self._device_lock:
+        with self._device():
             if self._res is None:
                 if self._k1:
                     tm1 = jax.tree.map(lambda a: a[0], self._ss.tm)
@@ -1249,7 +1259,7 @@ class TMService:
         policy loop does exactly that for its due members)."""
         if self.eval_x is None:
             raise ValueError("TMService built without an eval set")
-        with self._device_lock:
+        with self._device():
             acc = self._measure()
             self.history.append((self.steps, acc))
             if self.sc.history_limit is not None:
@@ -1282,7 +1292,7 @@ class TMService:
         the result becomes every member's known-good baseline."""
         xs = jnp.asarray(xs, dtype=bool)
         ys = jnp.asarray(ys, dtype=jnp.int32)
-        with self._device_lock:
+        with self._device():
             if self._res is not None:
                 raise ValueError(
                     "offline_train needs the full fleet device-resident; "
@@ -1406,7 +1416,7 @@ class TMService:
         analysis cadence, and apply the mitigation policy to due members.
         """
         budget = self.chunk if max_points is None else max_points
-        with self._device_lock:
+        with self._device():
             trained = self.drain(budget, on_chunk)
             self._ps.since += trained
             if self._auto:
@@ -1437,7 +1447,7 @@ class TMService:
         K = self.n_replicas
         mask = (np.ones(K, dtype=bool) if mask is None
                 else np.asarray(mask, dtype=bool))
-        with self._device_lock:
+        with self._device():
             accepted = self.submit_rows(xs, ys, mask)
             retry = mask & ~accepted
             if retry.any():
@@ -1464,7 +1474,7 @@ class TMService:
         fleet: the checkpoint is residency-agnostic and restores under
         any ``resident`` budget (migration across device budgets).
         Returns the checkpoint path."""
-        with self._device_lock:
+        with self._device():
             self.flush()
             ss_K, keys_K = self._assemble_plane()
             ps = self._ps
@@ -1553,7 +1563,7 @@ class TMService:
         replicas, capacity, packing — :meth:`restore` guarantees that);
         the ``resident`` budget may differ. Anything staged or held now
         is discarded: the checkpoint defines the complete state."""
-        with self._device_lock:
+        with self._device():
             # settle pending spills BEFORE the install clears the store —
             # a stale deferred snapshot must never land in the fresh one
             self._settle_spills()

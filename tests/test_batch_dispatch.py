@@ -18,7 +18,7 @@ from repro.core import (
 from repro.core import accuracy as acc_mod
 from repro.core import online as online_mod
 from repro.core import tm as tm_mod
-from repro.kernels import dispatch, ops, ref
+from repro.kernels import dispatch, ref
 
 # (C, J, L, B) — odd shapes on purpose: batch of 1, batch over the lane
 # count (257), literal axes that straddle the 128-lane tile boundary.
@@ -57,7 +57,9 @@ def test_clause_eval_batch_ref_pallas_bit_parity(shape):
     include, lits = _rand_case(shape, seed=11)
     for training in (True, False):
         a = ref.clause_eval_batch(include, lits, training=training)
-        b = ops.clause_eval_batch(include, lits, training=training)
+        b = dispatch.resolve("pallas").clause_eval_batch(
+            include, lits, training=training
+        )
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -74,12 +76,15 @@ def test_dispatch_registry_names_and_auto():
     assert set(dispatch.available()) >= {"ref", "pallas", "auto"}
     assert dispatch.resolve("ref").name == "ref"
     assert dispatch.resolve("pallas").name == "pallas"
-    # TM_BACKEND (the CI kernel-parity job) overrides auto-resolution;
-    # otherwise auto means pallas on TPU, ref elsewhere.
-    expect = os.environ.get(
-        "TM_BACKEND", "pallas" if jax.default_backend() == "tpu" else "ref"
-    )
+    # auto means pallas on TPU; elsewhere TM_BACKEND (the CI kernel-parity
+    # job) may override the ref default.
+    expect = ("pallas" if jax.default_backend() == "tpu"
+              else os.environ.get("TM_BACKEND") or "ref")
     assert dispatch.resolve("auto").name == expect
+    # interpret mode follows the platform, decided once by resolve
+    assert dispatch.resolve("pallas").interpret == (
+        jax.default_backend() != "tpu")
+    assert not dispatch.resolve("ref").interpret
     with pytest.raises(ValueError):
         dispatch.resolve("no-such-backend")
 

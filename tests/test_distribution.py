@@ -31,14 +31,14 @@ def test_spec_partition_rules():
 
 def test_fsdp_shards_largest_free_dim():
     # AbstractMesh: rule evaluation needs only mesh.shape, not real devices
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 16)))
+    mesh = jax.sharding.AbstractMesh((2, 16), ("data", "model"))
     pol = shd.ShardingPolicy()
     ps = shd.spec_partition(PSpec((128, 64), ("embed", "ff")), mesh, pol)
     assert ps == PS("data", "model")  # ff -> TP; fsdp picks embed over data
 
 
 def test_spec_partition_nondivisible_replicates():
-    mesh = jax.sharding.AbstractMesh((("model", 16),))
+    mesh = jax.sharding.AbstractMesh((16,), ("model",))
     pol = shd.ShardingPolicy(fsdp=False)
     ps = shd.spec_partition(PSpec((7, 3), ("kv_heads", "head_dim")), mesh, pol)
     assert ps == PS(None, None)
@@ -115,7 +115,11 @@ SMALL_MESH_SCRIPT = textwrap.dedent("""\
     from repro.models import params as P, stubs, transformer
 
     cfg = configs.get_smoke_config("granite_8b")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    # the model's sharding is GSPMD's: Auto axes (make_mesh defaults to
+    # Explicit, under which the batch-sharded embedding gather from the
+    # FSDP-sharded table names the data axis twice)
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     policy = shd.ShardingPolicy()
     specs = transformer.model_specs(cfg)
     prm = P.materialize(specs, jax.random.PRNGKey(0), jnp.float32)

@@ -494,3 +494,68 @@ def test_forced_4_device_mesh_subprocess():
                        capture_output=True, text=True, timeout=600, env=env)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "OK" in r.stdout
+
+
+FORCED_MESH_PALLAS_SCRIPT = textwrap.dedent("""\
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+    assert len(jax.devices()) == 4, jax.devices()
+
+    from repro.core import TMConfig, init_state
+    from repro.data import blocks, iris
+    from repro.eval.crossval import CrossValRun
+    from repro.serve import ServiceConfig, TMService
+
+    cfg = TMConfig(n_features=16, max_classes=3, max_clauses=16, n_states=16,
+                   backend="pallas")
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+
+    # sweeps over D = 4 orderings: R = 16 puts 4 replicas on each device
+    # (the r % D rule holds in place), R = 8 puts 2 (each shard gathers
+    # its own data streams)
+    osets, _ = blocks.iris_paper_sets(n_orderings=4)
+    for T in ((5, 15), (15,)):
+        args = (osets.offline_x, osets.offline_y, osets.validation_x,
+                osets.validation_y, (1.375, 3.0), T)
+        base = CrossValRun(cfg).sweep(*args, n_epochs=2, seed=0)
+        sharded = CrossValRun(cfg, mesh=mesh).sweep(*args, n_epochs=2, seed=0)
+        np.testing.assert_array_equal(
+            np.asarray(base.val_accuracy), np.asarray(sharded.val_accuracy))
+
+    # packed fleet: offline training, drain, analysis and serving
+    xs, ys = iris.load()
+    outs = []
+    for m in (None, mesh):
+        svc = TMService(cfg, init_state(cfg), ServiceConfig(
+            replicas=8, buffer_capacity=16, chunk=4, packed=True, s=3.0,
+            T=15, seed=list(range(8)), mesh=m), eval_x=xs[:30], eval_y=ys[:30])
+        svc.offline_train(xs[:20], ys[:20], n_epochs=1)
+        for i in range(8):
+            idx = (i + 7 * np.arange(8)) % 150
+            svc.submit_rows(xs[idx], ys[idx])
+        svc.tick(8)
+        outs.append((np.asarray(svc.ss.tm.ta_state), svc.serve(xs[:10]),
+                     svc.analyze()))
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(a, b)
+    print("OK")
+""")
+
+
+def test_forced_4_device_mesh_pallas_subprocess():
+    """The Pallas kernels on a forced 4-host-device mesh (each kernel runs
+    per device under shard_map): sweeps and a packed fleet are bitwise
+    equal to the 1-device runs."""
+    import os
+
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    r = subprocess.run([sys.executable, "-c", FORCED_MESH_PALLAS_SCRIPT],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "OK" in r.stdout

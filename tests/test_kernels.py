@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, packing, ref
+from repro.kernels import dispatch, packing, ref
 
 SHAPES = [
     (1, 2, 5),      # degenerate
@@ -30,22 +30,24 @@ def test_clause_eval_matches_ref(shape, training):
     include = jnp.asarray(rng.random((C, J, L)) < 0.3)
     lits = jnp.asarray(rng.random((L,)) < 0.5)
     want = ref.clause_eval(include, lits, training=training)
-    got = ops.clause_eval(include, lits, training=training)
+    got = dispatch.resolve("pallas").clause_eval(include, lits,
+                                                 training=training)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
 def test_clause_eval_all_excluded_is_empty():
     include = jnp.zeros((2, 4, 32), dtype=bool)
     lits = jnp.ones((32,), dtype=bool)
-    assert bool(jnp.all(ops.clause_eval(include, lits, training=True)))
-    assert not bool(jnp.any(ops.clause_eval(include, lits, training=False)))
+    kb = dispatch.resolve("pallas")
+    assert bool(jnp.all(kb.clause_eval(include, lits, training=True)))
+    assert not bool(jnp.any(kb.clause_eval(include, lits, training=False)))
 
 
 def test_clause_eval_single_violation_kills_clause():
     L = 32
     include = jnp.zeros((1, 2, L), dtype=bool).at[0, 0, 7].set(True)
     lits = jnp.ones((L,), dtype=bool).at[7].set(False)
-    out = ops.clause_eval(include, lits, training=True)
+    out = dispatch.resolve("pallas").clause_eval(include, lits, training=True)
     assert not bool(out[0, 0])  # included literal is 0 -> clause 0
     assert bool(out[0, 1])      # empty clause in training -> 1
 
@@ -67,7 +69,8 @@ def test_feedback_matches_ref(shape, policy, dtype):
         kw = dict(s=jnp.float32(1.375), n_states=n_states, s_policy=policy,
                   boost_true_positive=boost)
         want = ref.feedback_step(ta, lits, c_out, t1, t2, u, **kw)
-        got = ops.feedback_step(ta, lits, c_out, t1, t2, u, **kw)
+        got = dispatch.resolve("pallas").feedback_step(
+            ta, lits, c_out, t1, t2, u, **kw)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -78,7 +81,7 @@ def test_feedback_states_stay_in_bounds():
     lits = jnp.ones((L,), dtype=bool)
     ones = jnp.ones((C, J), dtype=bool)
     u = jnp.zeros((C, J, L), dtype=jnp.float32)  # every draw fires
-    out = ops.feedback_step(
+    out = dispatch.resolve("pallas").feedback_step(
         ta, lits, ones, ones, jnp.zeros_like(ones), u,
         s=jnp.float32(1.0), n_states=n, s_policy="standard",
         boost_true_positive=True,
@@ -95,8 +98,9 @@ def test_feedback_states_stay_in_bounds():
 
 
 @pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_batch_packed_matches_unpacked_oracle(shape, mod):
+    mod = dispatch.resolve(mod)
     C, J, L = shape
     rng = np.random.default_rng(hash(("packed",) + shape) % 2**31)
     include = jnp.asarray(rng.random((C, J, L)) < 0.3)
@@ -132,8 +136,9 @@ def _rep_inputs(R, D, C, J, L, seed=0):
 
 
 @pytest.mark.parametrize("shape", REP_SHAPES)
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_replicated_matches_stacked(shape, mod):
+    mod = dispatch.resolve(mod)
     R, D, C, J, L = shape
     inp = _rep_inputs(*shape, seed=hash(shape) % 2**31)
     for training in (True, False):
@@ -149,8 +154,9 @@ def test_clause_eval_replicated_matches_stacked(shape, mod):
 
 
 @pytest.mark.parametrize("shape", REP_SHAPES)
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_batch_replicated_matches_stacked(shape, mod):
+    mod = dispatch.resolve(mod)
     R, D, C, J, L = shape
     rng = np.random.default_rng(hash(shape) % 2**31)
     include = jnp.asarray(rng.random((R, C, J, L)) < 0.3)
@@ -165,8 +171,9 @@ def test_clause_eval_batch_replicated_matches_stacked(shape, mod):
 
 
 @pytest.mark.parametrize("shape", REP_SHAPES)
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_batch_replicated_packed_matches_unpacked(shape, mod):
+    mod = dispatch.resolve(mod)
     R, D, C, J, L = shape
     rng = np.random.default_rng(hash(("packed",) + shape) % 2**31)
     include = jnp.asarray(rng.random((R, C, J, L)) < 0.3)
@@ -186,10 +193,11 @@ def test_clause_eval_batch_replicated_packed_matches_unpacked(shape, mod):
 @pytest.mark.parametrize("shape", REP_SHAPES)
 @pytest.mark.parametrize("policy", ["standard", "hardware"])
 @pytest.mark.parametrize("dtype", [jnp.int8, jnp.int16])
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_feedback_replicated_matches_stacked(shape, policy, dtype, mod):
     """feedback_step_replicated == stacking per-replica feedback_step calls,
     bit for bit, on both backends (pallas in interpret mode)."""
+    mod = dispatch.resolve(mod)
     R, D, C, J, L = shape
     n_states = 50 if dtype == jnp.int8 else 5000
     rng = np.random.default_rng(hash((shape, policy)) % 2**31)

@@ -17,7 +17,7 @@ import pytest
 
 from repro.core import TMConfig, faults, init_runtime, init_state
 from repro.core import tm as tm_mod
-from repro.kernels import ops, packing, ref
+from repro.kernels import dispatch, packing, ref
 
 # f values straddling word boundaries: sub-word, word-1, word, word+1,
 # multi-word with tail, and the benchmark widths.
@@ -136,8 +136,9 @@ def _case(f, seed, C=3, J=6, B=17):
 
 
 @pytest.mark.parametrize("f", WIDTHS)
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_batch_packed_matches_unpacked(f, mod):
+    mod = dispatch.resolve(mod)
     include, lits, inc_p, lit_p = _case(f, seed=f)
     for training in (True, False):
         want = ref.clause_eval_batch(include, lits, training=training)
@@ -145,9 +146,10 @@ def test_clause_eval_batch_packed_matches_unpacked(f, mod):
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_batch_packed_empty_and_all_include(mod):
     """Edge banks: all-excluded (empty convention) and all-included."""
+    mod = dispatch.resolve(mod)
     f = 33
     inc_empty = jnp.zeros((2, 4, 2 * f), dtype=bool)
     inc_full = jnp.ones((2, 4, 2 * f), dtype=bool)
@@ -167,8 +169,9 @@ def test_clause_eval_batch_packed_empty_and_all_include(mod):
 
 @pytest.mark.parametrize("f", [16, 31, 49, 196])
 @pytest.mark.parametrize("RD", [(1, 1), (4, 2), (3, 3)])
-@pytest.mark.parametrize("mod", [ref, ops], ids=["ref", "pallas"])
+@pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_clause_eval_batch_replicated_packed_matches_unpacked(f, RD, mod):
+    mod = dispatch.resolve(mod)
     R, D = RD
     rng = np.random.default_rng(hash((f, R, D)) % 2**31)
     include = jnp.asarray(rng.random((R, 3, 6, 2 * f)) < 0.3)

@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import TMConfig, init_runtime, init_state, train_step
 from repro.core import tm as tm_mod
-from repro.kernels import ops, ref
+from repro.kernels import dispatch, ref
 
 _shapes = st.tuples(
     st.integers(1, 4),    # classes
@@ -28,7 +28,8 @@ def test_kernel_clause_eval_equals_oracle(shape, seed, training):
     include = jnp.asarray(rng.random((C, J, L)) < rng.random())
     lits = jnp.asarray(rng.random((L,)) < 0.5)
     want = ref.clause_eval(include, lits, training=training)
-    got = ops.clause_eval(include, lits, training=training)
+    got = dispatch.resolve("pallas").clause_eval(include, lits,
+                                                 training=training)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -52,7 +53,8 @@ def test_kernel_feedback_equals_oracle_and_bounds(shape, seed, s, policy):
     kw = dict(s=jnp.float32(s), n_states=n, s_policy=policy,
               boost_true_positive=bool(seed % 2))
     want = np.asarray(ref.feedback_step(ta, lits, c_out, t1, t2, u, **kw))
-    got = np.asarray(ops.feedback_step(ta, lits, c_out, t1, t2, u, **kw))
+    got = np.asarray(dispatch.resolve("pallas").feedback_step(
+        ta, lits, c_out, t1, t2, u, **kw))
     np.testing.assert_array_equal(want, got)
     # Invariants: states in [1, 2N]; |delta| <= 1 per TA per step.
     assert want.min() >= 1 and want.max() <= 2 * n
@@ -239,7 +241,7 @@ def test_kernel_feedback_replicated_equals_stacked_oracle(seed, shape, policy):
         ))
         for r in range(R)
     ])
-    for mod in (ref, ops):
+    for mod in (ref, dispatch.resolve("pallas")):
         got = np.asarray(mod.feedback_step_replicated(
             ta, lits, c_out, t1, t2, u, s=s, **kw
         ))
