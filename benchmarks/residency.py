@@ -164,8 +164,8 @@ def residency_bench(K: int, resident: int, rounds: int, active: int,
         "percohort_wall_s": wall_base,
         "percohort_trained_per_s": trained_base / wall_base,
         "speedup_vs_percohort": wall_base / wall,
-        "activations": int(svc._res.activations),
-        "evictions": int(svc._res.evictions),
+        "activations": svc.obs.counters()["residency.activations"],
+        "evictions": svc.obs.counters()["residency.evictions"],
         "evict_latency_s_per_replica": evict_s,
         "activate_latency_s_per_replica": act_s,
         "bitwise_identical": bitwise,

@@ -135,6 +135,7 @@ def activate_replicas(plane, act_plane, mask):
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("monitor",))
+@jax.named_scope("tm.drain")   # repro.serve.obs.DRAIN; core imports no serve
 def _consume_many_replicated(
     cfg: TMConfig,
     k: int,                 # static chunk size (one trace per chunk size)
@@ -211,6 +212,7 @@ def _consume_many_replicated(
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("monitor",))
+@jax.named_scope("tm.drain")   # repro.serve.obs.DRAIN; core imports no serve
 def _consume_many(
     cfg: TMConfig,
     k: int,                 # static chunk size (one trace per chunk size)

@@ -55,7 +55,7 @@ class ResidencyMap:
     without copy-on-write hazards.
     """
 
-    def __init__(self, n_replicas: int, n_slots: int):
+    def __init__(self, n_replicas: int, n_slots: int, obs):
         # <= (not <): the resident="auto" service may grow the plane to
         # the full fleet while keeping the residency layer's semantics
         # (uniform serve/evict surface across re-partitions).
@@ -71,8 +71,9 @@ class ResidencyMap:
         self.last_use = np.zeros(n_slots, dtype=np.int64)
         self._clock = 0
         self.store: dict[int, Any] = {}     # rid -> host snapshot tree
-        self.activations = 0                # lifetime counters (bench +
-        self.evictions = 0                  # observability)
+        # the owning service's repro.serve.obs.Obs: counts every
+        # activation and eviction, across re-partitions
+        self.obs = obs
         # EWMA of the per-round active-set size (replicas with buffered
         # rows AND budget per drain round) — the autotune signal.
         self.ewma_active = float("nan")
@@ -111,7 +112,7 @@ class ResidencyMap:
         slots = np.asarray(slots, dtype=np.int64)
         self.slot_of[rids] = slots
         self.replica_of[slots] = rids
-        self.activations += len(rids)
+        self.obs.add("residency.activations", len(rids))
         self.touch(slots)
 
     def release(self, slots) -> np.ndarray:
@@ -120,7 +121,7 @@ class ResidencyMap:
         rids = self.replica_of[slots].copy()
         self.slot_of[rids] = -1
         self.replica_of[slots] = -1
-        self.evictions += len(slots)
+        self.obs.add("residency.evictions", len(slots))
         return rids
 
     # -- slot-count autotuning (ServiceConfig(resident="auto")) -------------

@@ -41,9 +41,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import buffer as buf_mod
+from repro.serve import obs
 
 
 @partial(jax.jit, static_argnums=1)
+@jax.named_scope(obs.FLUSH)
 def _enqueue_rows(ss, block: int, xs, ys, counts):
     """Push up to ``counts[r]`` staged rows into EVERY replica's ring buffer.
 

@@ -48,6 +48,7 @@ from repro.core.tm import TMConfig, TMRuntime, TMState, init_runtime
 from repro.data import buffer as buf_mod
 from repro.distributed import sharding as shard_mod
 from repro.kernels import packing
+from repro.serve import obs as obs_mod
 from repro.serve import residency as res_mod
 from repro.serve import router as router_mod
 from repro.serve import tunable as tun_mod
@@ -55,6 +56,7 @@ from repro.train import checkpoint as ckpt_mod
 
 
 @jax.jit
+@jax.named_scope(obs_mod.DRAIN)
 def _advance_keys(keys, active):
     """Split every ACTIVE replica's RNG key; retired replicas keep theirs.
 
@@ -69,6 +71,7 @@ def _advance_keys(keys, active):
 
 
 @partial(jax.jit, static_argnums=(2,))
+@jax.named_scope(obs_mod.FLUSH)
 def _activate_enqueue_rows(ss, keys, block: int, act_mask, act_ss,
                            act_keys, xs, ys, counts):
     """A residency cohort's activation select FUSED with its superblock
@@ -406,6 +409,9 @@ class TMService:
         # slots; the rest spill as host snapshots sharing the broadcast
         # initial bank / empty buffer (snapshots are immutable in the
         # store, so sharing is safe).
+        # spans and counters (repro.serve.obs); spans only while a
+        # profiler trace is recording
+        self.obs = obs_mod.Obs()
         self._res: Optional[res_mod.ResidencyMap] = None
         self._best_host: Optional[np.ndarray] = None  # [K, C, J, L] banks
         self._auto = auto
@@ -422,7 +428,7 @@ class TMService:
         self._pending_spills: list = []
         self._pending_rids: set = set()
         if residency:
-            self._res = res_mod.ResidencyMap(K, P)
+            self._res = res_mod.ResidencyMap(K, P, self.obs)
             self._res.assign(np.arange(P), np.arange(P))
             keys_host = np.asarray(keys)
             buf_host = jax.tree.map(np.asarray, buf1)
@@ -584,7 +590,7 @@ class TMService:
         """
         K = self.n_replicas
         landed = np.zeros(K, dtype=np.int64)
-        with self._device():
+        with self._device(), self.obs.span(obs_mod.FLUSH):
             while True:
                 with self.router.lock:
                     block = self.router.take_block()
@@ -601,6 +607,7 @@ class TMService:
             self._ss, self.router.block, xs, ys, counts
         )
         acc = np.asarray(accepted, dtype=np.int64)
+        self._count_flush(acc.sum())
         with self.router.lock:
             self._dev_size -= counts - acc
             self.router.dropped += counts - acc
@@ -654,7 +661,9 @@ class TMService:
         self._ss, accepted = router_mod._enqueue_rows(
             self._ss, self.router.block, xs_p, ys_p, cnt_p
         )
-        return np.asarray(accepted, dtype=np.int64)[slots]
+        acc = np.asarray(accepted, dtype=np.int64)[slots]
+        self._count_flush(acc.sum())
+        return acc
 
     def _enqueue_cohort_batched(self, cohort, xs_c, ys_c,
                                 cnt_c) -> np.ndarray:
@@ -684,7 +693,16 @@ class TMService:
             )
             self._reshard_plane()
         self._settle_spills()
-        return np.asarray(accepted, dtype=np.int64)[slots]
+        acc = np.asarray(accepted, dtype=np.int64)[slots]
+        self._count_flush(acc.sum())
+        return acc
+
+    def _count_flush(self, rows) -> None:
+        """One ``_enqueue_rows`` dispatch: the rows it landed and the
+        slots its scan touched (every lane of the plane, whatever is
+        staged)."""
+        self.obs.add("flush.rows", rows)
+        self.obs.add("flush.slots", self.n_resident * self.router.block)
 
     def _reshard_plane(self) -> None:
         """Re-pin the device plane's sharding after a dispatch whose
@@ -948,33 +966,39 @@ class TMService:
         # can't desync accounting from the device
         with self._device():
             self.flush()
-            if self._res is None:
-                return (self._drain_k1(budget, on_chunk) if self._k1
-                        else self._drain_replicated(budget, on_chunk))
-            # Residency: sweep EVERY replica holding buffered rows (and
-            # budget) in cohorts of <= resident slots — no lane starves
-            # behind the working set, and sparse traffic only ever
-            # activates its own users. A replica with budget but no
-            # buffered rows is skipped entirely, so its RNG key does not
-            # split; the always-resident twin property therefore masks
-            # budgets by ``buffered > 0`` (tests/test_residency.py).
-            trained = np.zeros(K, dtype=np.int64)
-            with self.router.lock:
-                has_rows = self._dev_size > 0
-            todo = np.nonzero(has_rows & (budget > 0))[0]
-            # the active-set size is the autotune signal (§17): how many
-            # replicas actually need a slot this round
-            self._res.note_active(len(todo))
-            R = self.n_resident
-            for i in range(0, len(todo), R):
-                cohort = todo[i:i + R]
-                slots = self._ensure_resident(cohort)
-                budget_p = np.zeros(R, dtype=np.int64)
-                budget_p[slots] = budget[cohort]
-                trained_p = self._drain_replicated(budget_p, on_chunk)
-                trained[cohort] = trained_p[slots]
-            self._settle_spills()
-            return trained
+            with self.obs.span(obs_mod.DRAIN):
+                return self._drain_locked(budget, on_chunk)
+
+    def _drain_locked(self, budget, on_chunk) -> np.ndarray:
+        """:meth:`drain`'s chunk loop, after the flush."""
+        K = self.n_replicas
+        if self._res is None:
+            return (self._drain_k1(budget, on_chunk) if self._k1
+                    else self._drain_replicated(budget, on_chunk))
+        # Residency: sweep EVERY replica holding buffered rows (and
+        # budget) in cohorts of <= resident slots — no lane starves
+        # behind the working set, and sparse traffic only ever
+        # activates its own users. A replica with budget but no
+        # buffered rows is skipped entirely, so its RNG key does not
+        # split; the always-resident twin property therefore masks
+        # budgets by ``buffered > 0`` (tests/test_residency.py).
+        trained = np.zeros(K, dtype=np.int64)
+        with self.router.lock:
+            has_rows = self._dev_size > 0
+        todo = np.nonzero(has_rows & (budget > 0))[0]
+        # the active-set size is the autotune signal (§17): how many
+        # replicas actually need a slot this round
+        self._res.note_active(len(todo))
+        R = self.n_resident
+        for i in range(0, len(todo), R):
+            cohort = todo[i:i + R]
+            slots = self._ensure_resident(cohort)
+            budget_p = np.zeros(R, dtype=np.int64)
+            budget_p[slots] = budget[cohort]
+            trained_p = self._drain_replicated(budget_p, on_chunk)
+            trained[cohort] = trained_p[slots]
+        self._settle_spills()
+        return trained
 
     def _drain_replicated(self, budget, on_chunk) -> np.ndarray:
         K = len(budget)   # the device-plane length (= slots, not fleet K)
@@ -1330,8 +1354,10 @@ class TMService:
             return None
         if self._res is not None:
             return self._analyze_residency(due)
-        acc = self.analyze()
-        tm, rolled = self.policy.apply(self._ps, due, acc, self._ss.tm)
+        with self.obs.span(obs_mod.ANALYSIS):
+            acc = self.analyze()
+        with self.obs.span(obs_mod.POLICY):
+            tm, rolled = self.policy.apply(self._ps, due, acc, self._ss.tm)
         self._ss = self._ss._replace(tm=tm)
         return acc, rolled
 
@@ -1341,18 +1367,20 @@ class TMService:
         entry, then run the policy FSM with the known-good banks living
         host-side (one [K, ...] numpy array — ``_best_host`` — instead of
         a device-resident snapshot tree)."""
-        acc = self._measure()
-        missing = due & np.isnan(acc)
-        while missing.any():
-            ids = np.nonzero(missing)[0][: self.n_resident]
-            self._ensure_resident(ids)
-            fresh = self._measure()
-            acc = np.where(np.isnan(acc), fresh, acc).astype(np.float32)
+        with self.obs.span(obs_mod.ANALYSIS):
+            acc = self._measure()
             missing = due & np.isnan(acc)
+            while missing.any():
+                ids = np.nonzero(missing)[0][: self.n_resident]
+                self._ensure_resident(ids)
+                fresh = self._measure()
+                acc = np.where(np.isnan(acc), fresh, acc).astype(np.float32)
+                missing = due & np.isnan(acc)
         self.history.append((self.steps, acc))
         if self.sc.history_limit is not None:
             del self.history[:-self.sc.history_limit]
-        rolled = self._policy_apply_residency(due, acc)
+        with self.obs.span(obs_mod.POLICY):
+            rolled = self._policy_apply_residency(due, acc)
         return acc, rolled
 
     def _policy_apply_residency(self, due, acc) -> np.ndarray:
@@ -1703,11 +1731,10 @@ class TMService:
         ss_K, keys_K = self._assemble_plane()   # settles pending spills
         old = self._res
         self.n_resident = int(new_r)
-        res = res_mod.ResidencyMap(self.n_replicas, self.n_resident)
-        # lifetime counters and the autotune EWMA survive the resize;
-        # the LRU clock and assignment restart deterministically
-        res.activations = old.activations
-        res.evictions = old.evictions
+        res = res_mod.ResidencyMap(self.n_replicas, self.n_resident,
+                                   self.obs)
+        # the autotune EWMA survives the resize (the counters live in
+        # ``obs``); the LRU clock and assignment restart deterministically
         res.ewma_active = old.ewma_active
         self._res = res
         self.repartitions += 1

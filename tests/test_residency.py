@@ -152,7 +152,8 @@ def test_residency_twin_bitwise(packed):
             mask = res.buffered > 0
             res.tick()
             twin.tick(np.where(mask, twin.chunk, 0))
-    assert res._res.evictions > 10, "traffic never contended the slots"
+    assert res.obs.counters()["residency.evictions"] > 10, \
+        "traffic never contended the slots"
     _assert_same_state(twin, res)
     # prediction parity on the (identical) per-replica states
     xs = _RNG.random((5, F)) > 0.5
@@ -253,7 +254,7 @@ def test_sharded_residency_matches_unsharded_twin():
             mask = res.buffered > 0
             res.tick()
             twin.tick(np.where(mask, twin.chunk, 0))
-    assert res._res.evictions > 0
+    assert res.obs.counters()["residency.evictions"] > 0
     _assert_same_state(twin, res)
 
 
@@ -282,7 +283,8 @@ def test_multicohort_batched_matches_sync_oracle(backend, packed):
                 svc.submit_rows(x, y)
         for svc in (batched, oracle, twin):
             svc.tick(2)
-    assert batched._res.evictions > 10, "slots were never contended"
+    assert batched.obs.counters()["residency.evictions"] > 10, \
+        "slots were never contended"
     _assert_same_state(oracle, batched, "batched diverged from oracle")
     _assert_same_state(twin, batched, "batched diverged from twin")
 
