@@ -2,8 +2,10 @@
 
 The FPGA buffers online datapoints in RAM so none are dropped while the
 accuracy-analysis process stalls the consumer. Here the buffer is a fixed-shape
-ring in device memory (capacity x features + head/size scalars) updated with
-``dynamic_update_slice`` — bounded memory, pure-functional, scan/vmap friendly.
+ring in device memory (capacity x features + head/size scalars) — bounded
+memory, pure-functional, scan/vmap friendly. :func:`push` appends one row
+with ``dynamic_update_slice``; :func:`push_block` lands a whole staged block
+with static slices and selects only, so a vmapped fleet flush stays dense.
 """
 from __future__ import annotations
 
@@ -66,6 +68,43 @@ def push(buf: RingBuffer, x: jax.Array, y: jax.Array) -> tuple[RingBuffer, jax.A
         size=jnp.where(full, buf.size, buf.size + 1),
     )
     return out, ~full
+
+
+def push_block(buf: RingBuffer, xs: jax.Array, ys: jax.Array,
+               count: jax.Array) -> tuple[RingBuffer, jax.Array]:
+    """Append the first ``count`` rows of a staged block. Returns
+    (buffer, rows accepted).
+
+    Bitwise the same as ``count`` :func:`push` calls in order: the first
+    ``n = min(count, B, capacity - size)`` rows land at ring slots
+    ``(tail + i) mod capacity`` and the rest are rejected (a full ring stays
+    full). The block is padded to the ring's length, rotated by ``tail``
+    with a barrel shift (one static roll and select per bit of ``tail``)
+    and selected into the slots it lands in — no gather, scatter, dynamic
+    slice or loop, so under ``vmap`` every op stays dense over
+    ``[K, capacity, ...]`` and shard-local along K.
+    """
+    cap = buf.capacity
+    xs = xs[:cap].astype(buf.data_x.dtype)
+    ys = ys[:cap].astype(jnp.int32)
+    n = jnp.clip(jnp.minimum(count, cap - buf.size), 0, xs.shape[0])
+    tail = jnp.mod(buf.head + buf.size, cap)
+
+    def rotate(a):
+        pad = jnp.zeros((cap - a.shape[0],) + a.shape[1:], a.dtype)
+        a = jnp.concatenate([a, pad])
+        for k in range((cap - 1).bit_length()):
+            a = jnp.where((tail >> k) & 1, jnp.roll(a, 1 << k, axis=0), a)
+        return a
+
+    lands = jnp.mod(jnp.arange(cap, dtype=jnp.int32) - tail, cap) < n
+    out = RingBuffer(
+        data_x=jnp.where(lands[:, None], rotate(xs), buf.data_x),
+        data_y=jnp.where(lands, rotate(ys), buf.data_y),
+        head=buf.head,
+        size=buf.size + n,
+    )
+    return out, n
 
 
 def pop(buf: RingBuffer) -> tuple[RingBuffer, jax.Array, jax.Array, jax.Array]:

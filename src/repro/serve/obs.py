@@ -19,10 +19,11 @@ One small system, three parts:
   the profiler's state: no annotation, no clock read, no device sync.
 * **Counters**, always on, read as one snapshot by :meth:`Obs.counters`:
   ``flush.rows`` (rows landed in device buffers), ``flush.slots`` (plane
-  length x ingress block per enqueue dispatch: the slots its scan
-  touches), ``residency.activations`` / ``residency.evictions``, and the
-  process-wide ``jit.traces`` / ``jit.compile_s`` (jaxpr traces and
-  backend-compile seconds per function name, from ``jax.monitoring``).
+  length x ingress block per enqueue dispatch: the staging slots it
+  carries, filled or not), ``residency.activations`` /
+  ``residency.evictions``, and the process-wide ``jit.traces`` /
+  ``jit.compile_s`` (jaxpr traces and backend-compile seconds per
+  function name, from ``jax.monitoring``).
   Every jaxpr trace's time is also kept, so :func:`traces_between` counts
   the traces of any stretch of time.
 

@@ -37,7 +37,6 @@ from functools import partial
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.data import buffer as buf_mod
@@ -54,22 +53,14 @@ def _enqueue_rows(ss, block: int, xs, ys, counts):
     counts [K] i32 — ONE jitted dispatch
     lands a whole ingress block (rows keep their per-replica submission
     order; rows at index >= counts[r] are padding and never touch state).
-    Returns (new session state, accepted-row count [K] i32).
+    Each ring takes its block as one dense rotated write
+    (:func:`repro.data.buffer.push_block`), bitwise what ``counts[r]``
+    single-row pushes would leave. Returns (new session state,
+    accepted-row count [K] i32).
     """
-    def per_replica(buf, xr, yr, c):
-        def step(carry, inp):
-            b, acc = carry
-            x, y, i = inp
-            new_b, ok = buf_mod.push(b, x, y)
-            take = i < c
-            b = jax.tree.map(lambda a, o: jnp.where(take, a, o), new_b, b)
-            return (b, acc + (ok & take).astype(jnp.int32)), None
-
-        idx = jnp.arange(block, dtype=jnp.int32)
-        (buf, acc), _ = jax.lax.scan(step, (buf, jnp.int32(0)), (xr, yr, idx))
-        return buf, acc
-
-    bufs, accepted = jax.vmap(per_replica)(ss.buf, xs, ys, counts)
+    bufs, accepted = jax.vmap(buf_mod.push_block)(
+        ss.buf, xs[:, :block], ys[:, :block], counts
+    )
     return ss._replace(buf=bufs), accepted
 
 

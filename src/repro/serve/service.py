@@ -699,7 +699,7 @@ class TMService:
 
     def _count_flush(self, rows) -> None:
         """One ``_enqueue_rows`` dispatch: the rows it landed and the
-        slots its scan touched (every lane of the plane, whatever is
+        staging slots it carried (every lane of the plane, whatever is
         staged)."""
         self.obs.add("flush.rows", rows)
         self.obs.add("flush.slots", self.n_resident * self.router.block)

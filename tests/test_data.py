@@ -4,6 +4,7 @@ import hashlib
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -87,6 +88,83 @@ def test_ring_buffer_wraparound():
         buf, x, y, valid = buffer.pop(buf)
         assert bool(valid) and int(y) == 10 + round_
     assert int(buf.size) == 0
+
+
+def _push_rows(buf, xs, ys, count):
+    """The oracle: ``count`` single-row pushes, in order."""
+    accepted = 0
+    for i in range(min(count, xs.shape[0])):
+        buf, ok = buffer.push(buf, xs[i], ys[i])
+        accepted += int(ok)
+    return buf, accepted
+
+
+def _filled_ring(rng, cap, width, head, size, packed):
+    """A ring with random contents (stale slots included) at head/size."""
+    buf = buffer.make(cap, 32 * width if packed else width, packed=packed)
+    if packed:
+        data_x = rng.integers(0, 2**32, (cap, width), dtype=np.uint32)
+    else:
+        data_x = rng.random((cap, width)) < 0.5
+    return buf._replace(
+        data_x=jnp.asarray(data_x),
+        data_y=jnp.asarray(rng.integers(0, 10, cap, dtype=np.int32)),
+        head=jnp.int32(head), size=jnp.int32(size),
+    )
+
+
+def _staged_rows(rng, block, width, packed):
+    if packed:
+        xs = rng.integers(0, 2**32, (block, width), dtype=np.uint32)
+    else:
+        xs = rng.random((block, width)) < 0.5
+    return jnp.asarray(xs), jnp.asarray(
+        rng.integers(0, 10, block, dtype=np.int32))
+
+
+def _assert_rings_equal(got, want):
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+
+
+# (cap, block, head, size, count): rings that wrap, empty, nearly full and
+# full rings (rows rejected), counts 0, 1, B-1 and B, B == cap, B > cap,
+# and capacities that are not powers of two.
+PUSH_BLOCK_CASES = [
+    (8, 4, 6, 1, 4),     # tail 7: the block wraps past the ring's end
+    (8, 4, 7, 0, 3),     # empty ring, head at the last slot
+    (8, 4, 0, 0, 4),     # empty ring
+    (8, 4, 2, 6, 4),     # nearly full: 2 land, 2 rejected
+    (8, 4, 5, 7, 1),     # one slot left, one row
+    (8, 4, 5, 8, 3),     # full: every row rejected
+    (8, 4, 3, 2, 0),     # nothing staged
+    (8, 4, 1, 3, 3),     # count B - 1
+    (6, 6, 4, 0, 6),     # B == cap, empty ring, wraps
+    (6, 6, 4, 3, 6),     # B == cap, half full
+    (7, 3, 5, 2, 3),     # cap not a power of two, wraps
+    (5, 5, 3, 4, 5),     # cap 5: one lands
+    (1, 1, 0, 0, 1),     # one-slot ring
+    (4, 6, 3, 2, 6),     # a block longer than the ring: 2 land
+    (64, 32, 50, 20, 32),  # the benchmark's ring and block
+]
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["uint32", "bool"])
+@pytest.mark.parametrize("cap,block,head,size,count", PUSH_BLOCK_CASES)
+def test_push_block_equals_push_loop(cap, block, head, size, count, packed):
+    """A block lands bitwise as ``count`` single-row pushes would leave the
+    ring: contents, order, head, size and the accepted count."""
+    rng = np.random.default_rng(cap * 1000 + head * 10 + size)
+    width = 2 if packed else 5
+    buf = _filled_ring(rng, cap, width, head, size, packed)
+    xs, ys = _staged_rows(rng, block, width, packed)
+    want, want_acc = _push_rows(buf, xs, ys, count)
+    got, got_acc = jax.jit(buffer.push_block)(buf, xs, ys, jnp.int32(count))
+    _assert_rings_equal(got, want)
+    assert np.asarray(got_acc).dtype == np.int32
+    assert int(got_acc) == want_acc == min(count, cap - size)
 
 
 def test_mnist_shapes_and_class_balance():

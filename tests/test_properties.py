@@ -162,6 +162,41 @@ def test_ring_buffer_fifo_capacity_and_empty_pop(cap, vals, extra_pops):
     assert int(buf.size) == 0
 
 
+@settings(max_examples=20, deadline=None)
+@given(
+    cap=st.integers(1, 10),
+    head=st.integers(0, 9),
+    size=st.integers(0, 10),
+    block=st.integers(1, 10),
+    count=st.integers(0, 10),
+    seed=st.integers(0, 2**31 - 1),
+)
+def test_ring_push_block_equals_pushes(cap, head, size, block, count, seed):
+    """``push_block`` of a staged block's first ``count`` rows leaves the
+    ring, and reports the rows accepted, bitwise as ``count`` ``push``
+    calls would."""
+    from repro.data import buffer
+
+    head, size = head % cap, min(size, cap)
+    block, count = min(block, cap), min(count, block)
+    rng = np.random.default_rng(seed)
+    buf = buffer.make(cap, 3)._replace(
+        data_x=jnp.asarray(rng.random((cap, 3)) < 0.5),
+        data_y=jnp.asarray(rng.integers(0, 99, cap, dtype=np.int32)),
+        head=jnp.int32(head), size=jnp.int32(size),
+    )
+    xs = jnp.asarray(rng.random((block, 3)) < 0.5)
+    ys = jnp.asarray(rng.integers(0, 99, block, dtype=np.int32))
+    got, n = buffer.push_block(buf, xs, ys, jnp.int32(count))
+    want, accepted = buf, 0
+    for i in range(count):
+        want, ok = buffer.push(want, xs[i], ys[i])
+        accepted += int(ok)
+    assert int(n) == accepted
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @settings(max_examples=15, deadline=None)
 @given(
     block_len=st.integers(1, 8),

@@ -8,12 +8,15 @@ exactly once, in submission order, and every rejected one is a counted
 backpressure drop. Rows are tagged with a unique id encoded in the
 feature bits so reordering cannot hide.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import TMConfig, init_state
+from repro.data import buffer
 from repro.serve import AdaptPolicy, ServiceConfig, TMService
+from repro.serve import router as router_mod
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -434,3 +437,100 @@ def test_service_requires_eval_set_for_analysis():
     assert rep.accuracy is None
     assert isinstance(svc.policy, AdaptPolicy)
     assert jnp.ndim(svc.rt.s) == 0
+
+
+# ---------------------------------------------------------------------------
+# The flush kernel: one dense rotated write per ring, bitwise the old scan.
+
+# Per lane: head and size as fractions of the ring, and the rows staged.
+# An empty ring, a block that wraps, a full ring, a lane with nothing
+# staged (its rows all zero), a nearly full ring, one row, B - 1 rows into
+# a half-full ring.
+_LANES = [(0, 0, "B"), (0.9, 0.1, "B"), (0.5, 1.0, "B"), (0.3, 0.2, 0),
+          (0.6, 0.9, "B"), (0.99, 0.0, 1), (0.2, 0.5, "B-1")]
+
+
+def _lane_state(cap, block, head, size, count):
+    return (min(int(head * cap), cap - 1), int(size * cap),
+            {"B": block, "B-1": block - 1}.get(count, count))
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["uint32", "bool"])
+@pytest.mark.parametrize("cap,block", [(6, 3), (7, 7), (8, 4), (64, 32)])
+def test_enqueue_rows_equals_per_row_pushes(cap, block, packed):
+    """``_enqueue_rows`` over K lanes leaves every ring, and reports every
+    accepted count, bitwise as a loop of single-row pushes per lane."""
+    n_lanes = len(_LANES)
+    cfg = TMConfig(n_features=F, max_classes=3, max_clauses=16, n_states=16)
+    svc = TMService(cfg, init_state(cfg), ServiceConfig(
+        replicas=n_lanes, buffer_capacity=cap, chunk=CHUNK,
+        ingress_block=block, s=3.0, T=15, seed=0, packed=packed,
+    ))
+    rng = np.random.default_rng(cap * 100 + block)
+    buf = svc.ss.buf
+    width = buf.data_x.shape[-1]
+    if packed:
+        data_x = rng.integers(0, 2**32, (n_lanes, cap, width),
+                              dtype=np.uint32)
+        xs = rng.integers(0, 2**32, (n_lanes, block, width), dtype=np.uint32)
+    else:
+        data_x = rng.random((n_lanes, cap, width)) < 0.5
+        xs = rng.random((n_lanes, block, width)) < 0.5
+    ys = rng.integers(0, 3, (n_lanes, block), dtype=np.int32)
+    states = [_lane_state(cap, block, *lane) for lane in _LANES]
+    heads, sizes, counts = (np.asarray(v, np.int32) for v in zip(*states))
+    xs[counts == 0] = 0
+    ys[counts == 0] = 0
+    ss = svc.ss._replace(buf=buf._replace(
+        data_x=jnp.asarray(data_x),
+        data_y=jnp.asarray(rng.integers(0, 3, (n_lanes, cap), np.int32)),
+        head=jnp.asarray(heads), size=jnp.asarray(sizes),
+    ))
+    got, accepted = router_mod._enqueue_rows(ss, block, xs, ys, counts)
+    assert np.asarray(accepted).dtype == np.int32
+    for r in range(n_lanes):
+        ring = jax.tree.map(lambda a: a[r], ss.buf)
+        want = 0
+        for i in range(int(counts[r])):
+            ring, ok = buffer.push(ring, jnp.asarray(xs[r, i]),
+                                   jnp.asarray(ys[r, i]))
+            want += int(ok)
+        assert int(accepted[r]) == want
+        for g, w in zip(got.buf, ring):
+            g, w = np.asarray(g)[r], np.asarray(w)
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(g, w)
+    # the rest of the session state is left as it was
+    for g, w in zip(jax.tree.leaves(got.tm), jax.tree.leaves(ss.tm)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _primitives(jaxpr, out):
+    """Every primitive of a jaxpr, recursing into its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, out)
+                elif hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
+                    _primitives(sub.jaxpr, out)
+    return out
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["uint32", "bool"])
+def test_enqueue_rows_has_no_loop_scatter_or_dynamic_update(packed):
+    """The flush is dense: its jaxpr holds no loop, no scatter and no
+    dynamic update (under vmap those compile to per-tenant loops)."""
+    svc = _make_packed_service() if packed else _make_service()
+    ss = svc.ss
+    xs = np.zeros((K, BLOCK) + ss.buf.data_x.shape[2:],
+                  ss.buf.data_x.dtype)
+    jaxpr = jax.make_jaxpr(router_mod._enqueue_rows, static_argnums=1)(
+        ss, BLOCK, xs, np.zeros((K, BLOCK), np.int32),
+        np.zeros(K, np.int32))
+    prims = _primitives(jaxpr.jaxpr, set())
+    assert "jit" in prims and "select_n" in prims   # the recursion reached
+    banned = {"scan", "while", "scatter", "scatter-add", "scatter_add",
+              "dynamic_update_slice"}
+    assert not prims & banned, sorted(prims & banned)
