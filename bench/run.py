@@ -9,7 +9,8 @@ The cell (``BENCHMARK.json``) names a configuration
 pre-trained bank from ``--seed``, builds the service, warms every shape the
 window uses (set-up), drives the service's public surface for ``--seconds``
 (the window), then checks what the window produced against the plain
-reference (``bench/reference.py``) for tenants drawn from the seed. With
+reference the configuration names (``bench/reference.py`` unless its
+``reference`` key names another) for tenants drawn from the seed. With
 ``--trace 0`` the last line reports the cell's end-to-end metrics; with
 ``--trace 1`` the window is traced and the line reports its per-layer
 metrics, each read by ``bench/metrics/<metric>.py``.
@@ -127,11 +128,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if chips != 1:
         raise SystemExit(f"bench: {workload} asks for {chips} chips; the "
                          f"harness has no mesh path yet")
+    Reference = setup.reference(conf)
     devs = require_devices(chips, platform)
     import jax
     import jax.numpy as jnp
-
-    from bench.reference import Reference
 
     enable_cache()
     t_set = {"start": time.perf_counter()}
@@ -216,8 +216,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             f"{sum(b - a for a, b in sp):.6f} s")
     out: dict = {}
     if control:
-        out["control"] = control_readings(conf, ref_out, base, keys, rows,
-                                          tk, ev)
+        out["control"] = control_readings(Reference, conf, ref_out, base,
+                                          keys, rows, tk, ev)
 
     # end-to-end metrics
     e2e = {"setup_s": opened["setup_s"],
@@ -300,12 +300,12 @@ def per_layer(bm: dict, workload: str, ctx) -> dict:
     return out
 
 
-def control_readings(conf, ref_out, base, keys, rows, tk, ev) -> dict:
-    """The control: the reference in bfloat16 put in the program's place,
-    compared with the float32 reference as the program is."""
+def control_readings(Reference, conf, ref_out, base, keys, rows, tk,
+                     ev) -> dict:
+    """The control: the configuration's reference in bfloat16 put in the
+    program's place, compared with the float32 reference as the program
+    is."""
     import jax.numpy as jnp
-
-    from bench.reference import Reference
 
     low = Reference(conf, u_dtype=jnp.bfloat16)
     ctl = low.replay(np.asarray(base), keys, [r[0] for r in rows],

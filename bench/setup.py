@@ -2,10 +2,14 @@
 
 Everything a configuration or a traffic mix holds lives in its own data file
 (``bench/configs/<name>.json``, ``bench/traffic/<name>.json``), found by the
-name ``BENCHMARK.json`` gives it; nothing here names a cell.
+name ``BENCHMARK.json`` gives it. A configuration's file names its rows'
+generator (``data.kind``: ``bench/data/<kind>.py``) and its plain reference
+(``reference``: ``bench/<name>.py``, ``bench/reference.py`` by default);
+nothing here names a cell or a configuration.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
 
@@ -53,25 +57,55 @@ def tm_config(conf: dict):
     return TMConfig(**conf["machine"])
 
 
+def _member(package: str, name: str, attr: str, what: str):
+    """``attr`` of the module ``<package>.<name>`` under ``bench/``; a name
+    with no such file, or a file without ``attr``, exits naming the file."""
+    rel = "/".join(["bench", *package.split(".")[1:], f"{name}.py"])
+    if not name.isidentifier():
+        raise SystemExit(f"bench: {what} {name!r} names no file ({rel})")
+    try:
+        mod = importlib.import_module(f"{package}.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"{package}.{name}":
+            raise
+        raise SystemExit(f"bench: unknown {what} {name!r}: no {rel}") from None
+    if not hasattr(mod, attr):
+        raise SystemExit(f"bench: {what} {name!r}: {rel} has no {attr}")
+    return getattr(mod, attr)
+
+
+SPLIT = ("base_rows", "base_gap", "pool_rows", "eval_rows")
+
+
 def make_data(conf: dict, seed: int) -> dict:
     """Host arrays from the seed: ``base`` rows for the pre-trained bank,
     ``eval`` rows for the policy's analysis, ``pool`` rows for traffic, cut
-    in that order from the shuffled table (``base_gap`` rows skipped after
-    the base rows)."""
+    in that order from the shuffled rows (``base_gap`` rows skipped after
+    the base rows). The rows come from ``bench/data/<kind>.py``'s
+    ``load(seed, **opts)``, ``opts`` being the ``data`` keys other than
+    ``kind`` and the split's."""
     d = conf["data"]
-    if d["kind"] != "iris":
-        raise SystemExit(f"bench: unknown data kind {d['kind']!r}")
-    from bench.data import iris
-
-    xs, ys = iris.load(seed=seed)
+    opts = {k: v for k, v in d.items() if k != "kind" and k not in SPLIT}
+    load = _member("bench.data", d["kind"], "load", "data kind")
+    xs, ys = load(seed=seed, **opts)
     a = d["base_rows"]
     b = a + d.get("base_gap", 0)
     c = b + d["pool_rows"]
+    if len(xs) < c + d["eval_rows"]:
+        raise SystemExit(f"bench: data kind {d['kind']!r} gave {len(xs)} "
+                         f"rows; the split needs {c + d['eval_rows']}")
     return {
         "base": (xs[:a], ys[:a]),
         "pool": (xs[b:c], ys[b:c]),
         "eval": (xs[c:c + d["eval_rows"]], ys[c:c + d["eval_rows"]]),
     }
+
+
+def reference(conf: dict):
+    """The configuration's ``Reference`` class, from ``bench/<name>.py``
+    where ``name`` is its ``reference`` key (``reference`` when absent)."""
+    return _member("bench", conf.get("reference", "reference"), "Reference",
+                   "reference")
 
 
 def build_service(conf: dict, base_bank, eval_xy, seed: int):

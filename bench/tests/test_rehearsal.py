@@ -5,6 +5,7 @@ import json
 import os
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -33,6 +34,54 @@ def test_traced_run_reports_busy_and_window(cpu_run):  # noqa: F811
     assert res["correct"]
     assert res["device"]["window_s"] > 0
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_unknown_data_kind_exits_naming_its_file(cpu_run):  # noqa: F811
+    with pytest.raises(SystemExit, match="bench/data/nope.py"):
+        cpu_run(named("iris-catchup", data={"kind": "nope"}))
+
+
+def test_unknown_reference_exits_naming_its_file(cpu_run):  # noqa: F811
+    with pytest.raises(SystemExit, match="bench/nope.py"):
+        cpu_run(named("iris-catchup", reference="nope"))
+
+
+@pytest.fixture
+def spy_reference(monkeypatch):
+    """A test-only reference module, ``bench.spy_reference``: the plain
+    reference, recording each instance and its replays."""
+    import jax.numpy as jnp
+
+    from bench import reference
+
+    made = []
+
+    class Reference(reference.Reference):
+        def __init__(self, conf, u_dtype=jnp.float32):
+            super().__init__(conf, u_dtype)
+            self.replays = 0
+            made.append(self)
+
+        def replay(self, *a, **k):
+            self.replays += 1
+            return super().replay(*a, **k)
+
+    mod = types.ModuleType("bench.spy_reference")
+    mod.Reference = Reference
+    monkeypatch.setitem(sys.modules, "bench.spy_reference", mod)
+    return made
+
+
+def test_reference_named_by_the_configuration_is_used(  # noqa: F811
+        cpu_run, spy_reference):
+    import jax.numpy as jnp
+
+    res = cpu_run(named("iris-catchup", reference="spy_reference"),
+                  seconds=1.0, control=True)
+    assert res["correct"], res["checks"]
+    assert any(v > 0 for v in res["control"].values()), res["control"]
+    assert [r.u_dtype for r in spy_reference] == [jnp.float32, jnp.bfloat16]
+    assert [r.replays for r in spy_reference] == [1, 1]
 
 
 def _run_py(cwd, env_extra=None):

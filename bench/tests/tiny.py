@@ -1,5 +1,7 @@
 """Tiny cells for the CPU rehearsal: the real configuration and traffic
-files with the scale cut to what a test run holds (K=8 tenants)."""
+files with the scale cut to what a test run holds (K=8 tenants), and a
+digits machine built from the iris file in code, so that the data kind and
+the reference a configuration names are exercised end to end."""
 from __future__ import annotations
 
 import copy
@@ -9,11 +11,17 @@ import pytest
 from bench import peaks, setup
 
 
-def cell(config: str, traffic: str, K: int = 8, **traffic_over):
+def cell(config: str, traffic: str, K: int = 8, conf_over=None):
+    """``conf_over`` maps a configuration key to a value, or a group's key
+    to the entries of that group it replaces."""
     conf = copy.deepcopy(setup.load_config(config))
     tr = copy.deepcopy(setup.load_traffic(traffic))
     conf["tenants"] = K
-    tr.update(traffic_over)
+    for k, v in (conf_over or {}).items():
+        if isinstance(v, dict):
+            conf[k].update(v)
+        else:
+            conf[k] = v
     w = {"name": f"tiny-{traffic}", "config": config, "traffic": traffic,
          "chips": 1}
     return w, conf, tr
@@ -37,11 +45,16 @@ def cpu_run(monkeypatch):
     return go
 
 
+# 7x7 digits (f=49, 10 classes); 150 rows cover the iris file's split
+DIGITS = {"data": {"kind": "digits", "side": 7, "n_points": 150},
+          "machine": {"n_features": 49, "max_classes": 10}}
+
 CELLS = {
     "iris-catchup": ("tm-iris-paper-k4096", "catchup", {}),
+    "digits-catchup": ("tm-iris-paper-k4096", "catchup", DIGITS),
 }
 
 
-def named(name: str):
+def named(name: str, **conf_over):
     config, traffic, over = CELLS[name]
-    return cell(config, traffic, **over)
+    return cell(config, traffic, conf_over={**over, **conf_over})
