@@ -22,6 +22,11 @@ from repro.core.tm import TMConfig, TMRuntime, TMState
 from repro.kernels import dispatch
 
 
+# fold_in datum of a patch machine's patch-choice key: the row key folded
+# with it, apart from the row key's (selection, uniforms) split
+PATCH_DRAW = 1
+
+
 class StepAux(NamedTuple):
     """Per-step observability (feeds the accuracy/energy analysis blocks)."""
 
@@ -112,7 +117,15 @@ def train_update(
     want per-step inference-mode monitoring use :func:`train_step`; batched
     consumers (``online._consume_many``) hoist monitoring out of the serial
     scan and run it once per chunk through the batch-first clause kernel.
+
+    A patch machine runs as the R = D = 1 case of
+    :func:`train_update_replicated`, which holds its patch semantics.
     """
+    if cfg.patched:
+        st, votes, activity = train_update_replicated(
+            cfg, TMState(ta_state=state.ta_state[None]), rt, x[None],
+            y[None], key[None])
+        return TMState(ta_state=st.ta_state[0]), votes[0], activity[0]
     k_sel, k_u = jax.random.split(key)
     lits = tm_mod.make_literals(x)
     include = tm_mod.ta_actions(cfg, state, rt)
@@ -147,10 +160,7 @@ def train_step(
     new_state, votes, activity = train_update(cfg, state, rt, x, y, key)
 
     # Inference-mode prediction for monitoring (empty clauses vote 0).
-    lits = tm_mod.make_literals(x)
-    include = tm_mod.ta_actions(cfg, state, rt)
-    clauses_inf = tm_mod.eval_clauses(cfg, include, lits, rt, training=False)
-    votes_inf = tm_mod.class_sums(cfg, clauses_inf)
+    _, votes_inf = tm_mod.forward(cfg, state, rt, x, training=False)
     votes_inf = jnp.where(rt.class_mask, votes_inf, jnp.iinfo(jnp.int32).min)
     pred = jnp.argmax(votes_inf).astype(jnp.int32)
 
@@ -235,18 +245,36 @@ def train_update_replicated(
     replica, shared across a hyperparameter grid exactly as re-running
     :func:`train_update` per cell would). Returns (new_state,
     votes [R, C], activity [R]); unused outputs are DCE'd under jit.
+
+    A patch machine (``cfg.patched``, the Convolutional TM) evaluates each
+    clause over the row's patches and gives a firing clause feedback from
+    the literals of one patch it fired on, drawn with one float32 uniform
+    per clause: ``uniform(fold_in(row_key, PATCH_DRAW), (C, J))``, the row
+    key being ``key[d]`` (DESIGN.md §18). Every other draw is the plain
+    machine's.
     """
     R, D = _replica_counts(state, x)
     H = R // D
     k2 = jax.vmap(jax.random.split)(key)        # [D, 2, key]
     k_sel, k_u = k2[:, 0], k2[:, 1]
 
-    lits = tm_mod.make_literals(x)              # [D, L]
-    include = tm_mod.ta_actions(cfg, state, rt)  # [R, C, J, L] (masks broadcast)
-
     backend = dispatch.resolve(cfg.backend)
-    clauses_tr = backend.clause_eval_replicated(include, lits, training=True)
-    clauses_tr = clauses_tr & rt.clause_mask[None, None, :]
+    if cfg.patched:
+        include = tm_mod.ta_actions(cfg, state, rt)
+        u_patch = jax.vmap(lambda k: jax.random.uniform(
+            jax.random.fold_in(k, PATCH_DRAW),
+            (cfg.max_classes, cfg.max_clauses), dtype=jnp.float32,
+        ))(key)                                 # [D, C, J]
+        clauses_tr, lits = tm_mod.patch_training_clauses(
+            cfg, include, rt, x, u_patch)       # [R, C, J], [R, C, J, L]
+        feedback = backend.feedback_patch_replicated
+    else:
+        lits = tm_mod.make_literals(x)              # [D, L]
+        include = tm_mod.ta_actions(cfg, state, rt)  # [R, C, J, L] (masks broadcast)
+
+        clauses_tr = backend.clause_eval_replicated(include, lits, training=True)
+        clauses_tr = clauses_tr & rt.clause_mask[None, None, :]
+        feedback = backend.feedback_step_replicated
     votes = tm_mod.class_sums(cfg, clauses_tr)  # [R, C]
 
     T_rep = jnp.broadcast_to(jnp.asarray(rt.T, jnp.int32), (R,))
@@ -263,7 +291,7 @@ def train_update_replicated(
         )
     )(k_u)                                      # [D, C, J, L] — factored draws
 
-    new_ta = backend.feedback_step_replicated(
+    new_ta = feedback(
         state.ta_state, lits, clauses_tr, type1, type2, u,
         s=jnp.broadcast_to(jnp.asarray(rt.s, jnp.float32), (R,)),
         n_states=cfg.n_states, s_policy=cfg.s_policy,

@@ -25,6 +25,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import dispatch, packing
 
@@ -49,8 +50,29 @@ class TMConfig:
     s_policy: str = "standard"       # "standard" | "hardware"  (see DESIGN.md §2)
     boost_true_positive: bool = True # deterministic strengthen on (clause=1,lit=1)
     backend: str = "auto"            # kernel backend name (see kernels/dispatch.py)
+    # Convolutional TM (Granmo et al., arXiv:1905.09688): a row is an image
+    # of (height, width) pixels, n_features = height * width, and a clause
+    # is one conjunction over the literals of a (height, width) window
+    # sliding with stride 1 (DESIGN.md §18). None: the plain TM.
+    image: Optional[tuple[int, int]] = None
+    window: Optional[tuple[int, int]] = None
 
     def __post_init__(self):
+        if (self.image is None) != (self.window is None):
+            raise ValueError("image and window are given together or not at all")
+        if self.image is not None:
+            # frozen: JSON lists become the hashable tuples a static jit
+            # argument needs
+            object.__setattr__(self, "image", tuple(int(v) for v in self.image))
+            object.__setattr__(self, "window", tuple(int(v) for v in self.window))
+            (h, w), (wh, ww) = self.image, self.window
+            if not (1 <= wh <= h and 1 <= ww <= w):
+                raise ValueError(f"window {self.window} must fit image {self.image}")
+            if self.n_features != h * w:
+                raise ValueError(
+                    f"n_features {self.n_features} must be the image's "
+                    f"{h} x {w} pixels"
+                )
         if self.max_clauses % 2:
             raise ValueError("max_clauses must be even (half +, half - polarity)")
         if self.n_states < 1:
@@ -64,8 +86,33 @@ class TMConfig:
             )
 
     @property
+    def patched(self) -> bool:
+        """Whether clauses run over the patches of an image (the CTM)."""
+        return self.window is not None
+
+    @property
+    def patch_grid(self) -> tuple[int, int]:
+        """(rows, columns) of window positions: stride 1, no padding."""
+        (h, w), (wh, ww) = self.image, self.window
+        return h - wh + 1, w - ww + 1
+
+    @property
+    def n_patches(self) -> int:
+        py, px = self.patch_grid
+        return py * px
+
+    @property
+    def clause_features(self) -> int:
+        """Features a clause sees: the row's, or a patch's window pixels
+        followed by thermometer codes of its x and y position."""
+        if not self.patched:
+            return self.n_features
+        py, px = self.patch_grid
+        return self.window[0] * self.window[1] + (px - 1) + (py - 1)
+
+    @property
     def n_literals(self) -> int:
-        return 2 * self.n_features
+        return 2 * self.clause_features
 
     @property
     def state_dtype(self):
@@ -150,6 +197,87 @@ def make_literals(x: jax.Array) -> jax.Array:
     """Boolean features -> literal vector [x, ~x] (length 2f)."""
     x = x.astype(bool)
     return jnp.concatenate([x, ~x], axis=-1)
+
+
+# Device scope of patch-literal building and patch evaluation (the name
+# repro.serve.obs documents; core imports no serve).
+PATCH_SCOPE = "tm.patch"
+
+
+def _patch_positions(image: tuple, window: tuple) -> np.ndarray:
+    """[P, (px - 1) + (py - 1)] bool: each patch's x then y thermometer
+    bits, patches in raster order (p = y * px + x)."""
+    (h, w), (wh, ww) = image, window
+    py, px = h - wh + 1, w - ww + 1
+    ys, xs = np.divmod(np.arange(py * px), px)
+    return np.concatenate([np.arange(px - 1)[None] < xs[:, None],
+                           np.arange(py - 1)[None] < ys[:, None]], axis=1)
+
+
+@jax.named_scope(PATCH_SCOPE)
+def make_patch_literals(cfg: TMConfig, x: jax.Array) -> jax.Array:
+    """Image rows [..., h*w] bool (row-major) -> patch literals [..., P, L].
+
+    The Convolutional TM's input (Granmo et al., arXiv:1905.09688, §2):
+    the window slides with stride 1 and no padding; patch ``p = y * px + x``
+    has the features of its window's pixels in row-major order, then
+    ``px - 1`` x-bits (bit i is 1 iff i < x), then ``py - 1`` y-bits (the
+    same rule for y); its literals are ``[features, ~features]``.
+    """
+    (h, w), (wh, ww) = cfg.image, cfg.window
+    py, px = cfg.patch_grid
+    lead = x.shape[:-1]
+    img = x.astype(bool).reshape(lead + (h, w))
+    pix = jnp.stack([img[..., dy:dy + py, dx:dx + px]
+                     for dy in range(wh) for dx in range(ww)], axis=-1)
+    pos = _patch_positions(cfg.image, cfg.window)
+    feats = jnp.concatenate([
+        pix.reshape(lead + (py * px, wh * ww)),
+        jnp.broadcast_to(pos, lead + pos.shape),
+    ], axis=-1)
+    return make_literals(feats)
+
+
+@jax.named_scope(PATCH_SCOPE)
+def eval_patch_clauses(
+    cfg: TMConfig,
+    include: jax.Array,   # [R, C, J, L] bool (post-fault actions)
+    xs: jax.Array,        # [D, B, f] bool image rows — replica r reads r % D
+    *,
+    training: bool,
+) -> jax.Array:
+    """Patch machines' clause outputs [R, B, C, J]: OR over patches."""
+    return dispatch.resolve(cfg.backend).patch_clause_eval_replicated(
+        include, make_patch_literals(cfg, xs), training=training
+    )[0]
+
+
+@jax.named_scope(PATCH_SCOPE)
+def patch_training_clauses(
+    cfg: TMConfig,
+    include: jax.Array,   # [R, C, J, L] bool (post-fault actions)
+    rt: TMRuntime,
+    x: jax.Array,         # [D, f] bool — one image row per data stream
+    u: jax.Array,         # [D, C, J] f32 — the patch-choice draws
+):
+    """Training-mode clause outputs [R, C, J] of one row per replica, and
+    the literals [R, C, J, L] of the patch each clause chose. A clause that
+    fired on no patch gets patch 0's literals, which its feedback ignores:
+    Type I then only erases, and Type II needs a firing clause."""
+    R, D = include.shape[0], x.shape[0]
+    lits = make_patch_literals(cfg, x)                        # [D, P, L]
+    out, chosen = dispatch.resolve(cfg.backend).patch_clause_eval_replicated(
+        include, lits[:, None], u[:, None], training=True
+    )
+    rows = jnp.tile(lits, (R // D, 1, 1))                     # replica r: r % D
+    picked = jax.vmap(lambda l, c: l[c])(rows, chosen[:, 0])  # [R, C, J, L]
+    return out[:, 0] & rt.clause_mask, picked
+
+
+def _refuse_patched(cfg: TMConfig, what: str) -> None:
+    if cfg.patched:
+        raise ValueError(f"{what} is not supported for patch machines "
+                         "(TMConfig.window); see DESIGN.md §18")
 
 
 def ta_actions(cfg: TMConfig, state: TMState, rt: TMRuntime) -> jax.Array:
@@ -263,6 +391,11 @@ def forward(
     training: bool = False,
 ):
     """One datapoint through the datapath. Returns (clause_out [C,J], votes [C])."""
+    if cfg.patched:
+        clauses, votes = forward_batch_replicated(
+            cfg, TMState(ta_state=state.ta_state[None]), rt, x[None, None],
+            training=training)
+        return clauses[0, 0], votes[0, 0]
     lits = make_literals(x)
     include = ta_actions(cfg, state, rt)
     clauses = eval_clauses(cfg, include, lits, rt, training=training)
@@ -286,6 +419,11 @@ def forward_batch(
     AND+popcount kernels with no call-site changes. Outputs are
     bit-identical across the two routes.
     """
+    if cfg.patched:
+        clauses, votes = forward_batch_replicated(
+            cfg, TMState(ta_state=state.ta_state[None]), rt, xs[None],
+            training=training)
+        return clauses[0], votes[0]
     if xs.dtype == jnp.uint32:
         lits = make_literals_packed(xs, cfg.n_features)
         include = ta_actions_packed(cfg, state, rt)
@@ -343,8 +481,16 @@ def forward_batch_replicated(
     :func:`forward_batch` on batch ``r % D`` bit-for-bit (the kernel
     contract's stacking guarantee). ``xs`` may be PACKED features
     [D, B, ceil(f/32)] uint32 (§13) — dtype routing, bit-identical.
+
+    A patch machine's rows are images: packed rows unpack, and every
+    clause is OR-ed over the patches (:func:`eval_patch_clauses`).
     """
-    if xs.dtype == jnp.uint32:
+    if cfg.patched:
+        if xs.dtype == jnp.uint32:
+            xs = packing.unpack_bits(xs, cfg.n_features)
+        clauses = eval_patch_clauses(
+            cfg, ta_actions(cfg, state, rt), xs, training=training)
+    elif xs.dtype == jnp.uint32:
         lits = make_literals_packed(xs, cfg.n_features)  # [D, B, W]
         include = ta_actions_packed(cfg, state, rt)      # [R, C, J, W]
         clauses = dispatch.resolve(
@@ -424,6 +570,7 @@ def forward_batch_pruned(
     term-for-term a reordering of :func:`forward_batch`'s — bitwise
     identical votes, hence bitwise identical predictions.
     """
+    _refuse_patched(cfg, "budgeted (pruned) inference")
     kb = dispatch.resolve(cfg.backend)
     if xs.dtype == jnp.uint32:
         lits = make_literals_packed(xs, cfg.n_features)
@@ -475,6 +622,7 @@ def forward_batch_pruned_replicated(
     Every replica serves from its OWN ranked clause subset (and weight
     plane) in one contraction over the compacted banks.
     """
+    _refuse_patched(cfg, "budgeted (pruned) inference")
     kb = dispatch.resolve(cfg.backend)
     if xs.dtype == jnp.uint32:
         lits = make_literals_packed(xs, cfg.n_features)

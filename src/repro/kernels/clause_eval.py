@@ -563,3 +563,120 @@ def clause_eval_batch_pruned_replicated_packed(
         _ref.gather_include(include_packed, sel), literals_packed,
         training=training, interpret=interpret,
     )
+
+
+# ---------------------------------------------------------------------------
+# Patch machines: the Convolutional TM (Granmo et al., arXiv:1905.09688).
+#
+# One MXU matmul per (replica, clause block, row) gives every patch's
+# violation count for every clause of the block,
+#
+#     violations[p, cj] = sum_l (1 - literal[p, l]) * include[cj, l]
+#
+# with patches on sublanes and clauses on lanes, so the OR over patches and
+# the patch choice are sublane reductions into lane-dense rows. The row
+# after the last patch carries ones over the literals: its "violations" are
+# the clause's include count (the emptiness test at inference). The patch
+# choice needs the running count of firing patches, a second matmul against
+# a lower-triangular ones matrix. Unlike the plain kernels this one is bound
+# by compute: P x L x CJ multiply-adds per row.
+# ---------------------------------------------------------------------------
+
+BLK_PCJ = 1024   # clauses per block (lanes of the violation tile)
+
+
+def _patch_pad(P: int, L: int, cj: int) -> tuple[int, int, int, int]:
+    """(padded patches + count row, padded literals, clause block, padded
+    clauses) of a patch evaluation."""
+    Pp = -(-(P + 1) // 32) * 32
+    Lp = -(-L // LANES) * LANES
+    blk = min(BLK_PCJ, -(-cj // LANES) * LANES)
+    return Pp, Lp, blk, -(-cj // blk) * blk
+
+
+def _patch_kernel(n_patches: int, training: bool, choose: bool, *refs):
+    # neg: [1, 1, Pp, Lp] int8 (1 - literal per patch; row P = ones),
+    # inc: [1, Lp, blk] int8 -> out [1, 1, 1 + choose, blk] i32:
+    # row 0 the clause output, row 1 the chosen patch.
+    if choose:
+        neg_ref, inc_ref, tri_ref, u_ref, out_ref = refs
+    else:
+        neg_ref, inc_ref, out_ref = refs
+    v = jnp.dot(neg_ref[0, 0], inc_ref[0], preferred_element_type=jnp.int32)
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    fired = ((v == 0) & (row < n_patches)).astype(jnp.int32)   # [Pp, blk]
+    hit = jnp.max(fired, axis=0, keepdims=True)                # [1, blk]
+    if not training:
+        n_inc = jnp.sum(jnp.where(row == n_patches, v, 0), axis=0,
+                        keepdims=True)
+        hit = jnp.where(n_inc == 0, 0, hit)
+    out_ref[0, 0, 0:1, :] = hit
+    if choose:
+        cum = jnp.dot(tri_ref[...], fired.astype(jnp.int8),
+                      preferred_element_type=jnp.int32)        # [Pp, blk]
+        m = jnp.sum(fired, axis=0, keepdims=True)
+        t = jnp.floor(u_ref[0, 0] * m.astype(jnp.float32)).astype(jnp.int32)
+        t = jnp.minimum(t, m - 1)
+        out_ref[0, 0, 1:2, :] = jnp.sum((cum <= t).astype(jnp.int32), axis=0,
+                                        keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("training", "interpret"))
+def patch_clause_eval_replicated(
+    include: jax.Array,    # [R, CJ, L] int8/bool — flattened (class, clause)
+    literals: jax.Array,   # [D, B, P, L] bool — replica r reads batch r % D
+    u: jax.Array | None,   # [D, B, CJ] f32 uniforms for the patch choice
+    *,
+    training: bool,
+    interpret: bool,
+):
+    """(clause_out [R, B, CJ] bool, chosen [R, B, CJ] i32) in one launch,
+    or clause_out alone without ``u``: the contract of
+    ``ref.patch_clause_eval_replicated`` on flattened clauses.
+
+    Grid (replica, clause block, row); the row axis is innermost, so a
+    replica's include block stays in VMEM across its rows. The include
+    bank is passed transposed ([R, L, CJ]) so that the matmul is the plain
+    (patches x literals) @ (literals x clauses) form.
+    """
+    R, cj, L = include.shape
+    D, B, P, _ = literals.shape
+    if R % D:
+        raise ValueError(f"data replicas {D} must divide replicas {R}")
+    Pp, Lp, blk, cjp = _patch_pad(P, L, cj)
+    choose = u is not None
+
+    inc = jnp.zeros((R, Lp, cjp), dtype=jnp.int8).at[:, :L, :cj].set(
+        jnp.swapaxes(include.astype(jnp.int8), 1, 2)
+    )
+    neg = jnp.zeros((D, B, Pp, Lp), dtype=jnp.int8)
+    neg = neg.at[:, :, :P, :L].set(1 - literals.astype(jnp.int8))
+    neg = neg.at[:, :, P, :L].set(1)
+    operands = [neg, inc]
+    in_specs = [
+        pl.BlockSpec((1, 1, Pp, Lp), lambda r, i, b: (r % D, b, 0, 0)),
+        pl.BlockSpec((1, Lp, blk), lambda r, i, b: (r, 0, i)),
+    ]
+    if choose:
+        operands += [
+            jnp.tril(jnp.ones((Pp, Pp), dtype=jnp.int8)),
+            jnp.zeros((D, B, 1, cjp), dtype=jnp.float32).at[:, :, 0, :cj].set(
+                u.astype(jnp.float32)),
+        ]
+        in_specs += [
+            pl.BlockSpec((Pp, Pp), lambda r, i, b: (0, 0)),
+            pl.BlockSpec((1, 1, 1, blk), lambda r, i, b: (r % D, b, 0, i)),
+        ]
+    nout = 2 if choose else 1
+    out = pl.pallas_call(
+        functools.partial(_patch_kernel, P, training, choose),
+        grid=(R, cjp // blk, B),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, nout, blk),
+                               lambda r, i, b: (r, b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((R, B, nout, cjp), jnp.int32),
+        interpret=interpret,
+        name="patch_clause_eval_replicated",
+    )(*operands)
+    hit = out[:, :, 0, :cj] != 0
+    return (hit, out[:, :, 1, :cj]) if choose else hit

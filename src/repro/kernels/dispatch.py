@@ -97,6 +97,26 @@ class KernelBackend(NamedTuple):
       R independent TA-bank updates in one fused plane (ref: one [R, C·J, L]
       elementwise pass; pallas: a 2-D (replica, clause-block) grid); MUST
       equal stacking ``feedback_step`` per replica bit-for-bit.
+
+    Patch machines (the Convolutional TM, DESIGN.md §18) have two entries of
+    their own; a plain machine never calls them:
+
+    * ``patch_clause_eval_replicated(include [R,C,J,L] bool,
+      literals [D,B,P,L] bool, u [D,B,C,J] f32 | None, *, training)
+      -> (clause_out [R,B,C,J] bool, chosen [R,B,C,J] i32 | None)`` — each
+      clause's conjunction on every one of a row's P patches, OR-ed over
+      the patches (empty clauses as in ``clause_eval``). With ``u`` (one
+      uniform per clause per row), ``chosen`` is the
+      ``min(floor(u*m), m-1)``-th of the m patches the clause fired on, in
+      patch order, and 0 when m = 0. Replica ``r`` reads batch ``r % D``;
+      MUST equal stacking the entry per replica and per row bit-for-bit
+      (pallas: a (replica, clause-block, row) grid, compute-bound).
+    * ``feedback_patch_replicated(ta_state [R,C,J,L], literals [R,C,J,L],
+      clause_out [R,C,J], type1_sel, type2_sel, u [D,C,J,L], *, s [R],
+      n_states, s_policy, boost_true_positive) -> [R,C,J,L]`` —
+      ``feedback_step_replicated`` with one literal row per clause (the
+      chosen patch's); MUST equal that entry wherever every clause's row
+      equals the replica's one row.
     """
 
     name: str
@@ -113,9 +133,11 @@ class KernelBackend(NamedTuple):
     clause_eval_batch_pruned_replicated_packed: Callable[..., jax.Array]
     feedback_step: Callable[..., jax.Array]
     feedback_step_replicated: Callable[..., jax.Array]
+    patch_clause_eval_replicated: Callable[..., tuple]
+    feedback_patch_replicated: Callable[..., jax.Array]
 
 
-# The 12 kernel entries of the contract (every field after name/interpret).
+# The 14 kernel entries of the contract (every field after name/interpret).
 ENTRIES: tuple[str, ...] = KernelBackend._fields[2:]
 
 # Factories, not instances: "pallas" must not import Pallas machinery unless

@@ -58,10 +58,12 @@ def _kernel(n_states: int, ta_ref, lit_ref, ctl_ref, u_ref, p_ref, out_ref):
 
 
 def _kernel_replicated(n_states: int, ta_ref, lit_ref, ctl_ref, u_ref, p_ref,
-                       out_ref):
+                       out_ref, *, per_clause: bool = False):
     # Refs carry a leading replica-block dim of 1: [1, BLK, Lp] / [1, 1, Lp].
     ta = ta_ref[...].astype(jnp.int32)        # [1, BLK, Lp]
-    lit = lit_ref[...] != 0                   # [1, 1, Lp] bool
+    # [1, 1, Lp] bool, or with ``per_clause`` one row per clause [1, BLK, Lp],
+    # widened first: Mosaic cannot relayout an int8-tiled mask of that shape
+    lit = (lit_ref[...].astype(jnp.int32) if per_clause else lit_ref[...]) != 0
     ctl = ctl_ref[...]                        # [1, BLK, LANES] int8
     u = u_ref[...]                            # [1, BLK, Lp] f32
     p = p_ref[...]                            # [1, 1, LANES] f32 (per-replica)
@@ -207,3 +209,65 @@ def feedback_plane_replicated(
         interpret=interpret,
     )(ta, lit, ctl, up, p)
     return out[:, :cj, :L]
+
+
+# Clause rows per block of the patch feedback kernel. Its operands are
+# [rows, literals] planes, taken unpadded: a block spans the whole literal
+# axis, and the last block of clauses may run past the plane's end (Pallas
+# drops what falls outside; rows are independent). So the wide uniform
+# plane is read where it lies, with no padded copy.
+BLK_PCJ = 512
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_states", "interpret")
+)
+def feedback_patch_replicated(
+    ta_state: jax.Array,    # [R, CJ, L] int8/int16
+    literals: jax.Array,    # [R, CJ, L] bool — each clause's chosen patch
+    clause_out: jax.Array,  # [R, CJ] bool
+    type1_sel: jax.Array,   # [R, CJ] bool
+    type2_sel: jax.Array,   # [R, CJ] bool
+    u: jax.Array,           # [D, CJ, L] f32 — replica r reads row r % D
+    p_strengthen: jax.Array,  # [R] f32
+    p_erase: jax.Array,       # [R] f32
+    *,
+    n_states: int,
+    interpret: bool,
+) -> jax.Array:
+    """:func:`feedback_plane_replicated` for patch machines: the literal
+    operand is one row per clause (the literals of the patch the clause
+    chose), blocked like the bank, instead of one row per replica. The
+    kernel body is the same. Returns new ta_state [R, CJ, L]."""
+    R, cj, L = ta_state.shape
+    D = u.shape[0]
+    if R % D:
+        raise ValueError(f"data replicas {D} must divide replicas {R}")
+    blk = min(BLK_PCJ, -(-cj // BLK_CJ) * BLK_CJ)
+
+    # one padded write: three column updates of this 128-lane plane took
+    # about as long as the kernel itself on a v5e
+    ctl = jnp.pad(
+        jnp.stack([clause_out, type1_sel, type2_sel], axis=-1).astype(jnp.int8),
+        ((0, 0), (0, 0), (0, LANES - 3)),
+    )
+    p = jnp.zeros((R, 1, LANES), dtype=jnp.float32)
+    p = p.at[:, 0, 0].set(p_strengthen.astype(jnp.float32))
+    p = p.at[:, 0, 1].set(p_erase.astype(jnp.float32))
+
+    plane = pl.BlockSpec((1, blk, L), lambda r, i: (r, i, 0))
+    return pl.pallas_call(
+        functools.partial(_kernel_replicated, n_states, per_clause=True),
+        grid=(R, pl.cdiv(cj, blk)),
+        in_specs=[
+            plane,
+            plane,
+            pl.BlockSpec((1, blk, LANES), lambda r, i: (r, i, 0)),
+            pl.BlockSpec((1, blk, L), lambda r, i: (r % D, i, 0)),
+            pl.BlockSpec((1, 1, LANES), lambda r, i: (r, 0, 0)),
+        ],
+        out_specs=plane,
+        out_shape=jax.ShapeDtypeStruct((R, cj, L), ta_state.dtype),
+        interpret=interpret,
+        name="feedback_patch_replicated",
+    )(ta_state, literals.astype(jnp.int8), ctl, u.astype(jnp.float32), p)

@@ -266,3 +266,61 @@ def feedback_step_replicated(
         replicas=R,
     )
     return out.reshape(R, C, J, L)
+
+
+def patch_clause_eval_replicated(
+    include: jax.Array, literals: jax.Array, u: jax.Array | None = None, *,
+    training: bool, interpret: bool,
+) -> tuple[jax.Array, jax.Array | None]:
+    """[R, C, J, L] x [D, B, P, L] (x u [D, B, C, J]) -> (clause_out
+    [R, B, C, J], chosen [R, B, C, J] | None) (see
+    ref.patch_clause_eval_replicated)."""
+    R, C, J, L = include.shape
+    D, B = literals.shape[:2]
+    kernel = partial(_ce.patch_clause_eval_replicated, training=training,
+                     interpret=interpret)
+    inc = include.reshape(R, C * J, L)
+    if u is None:
+        out = _per_device(lambda i, l: kernel(i, l, None), inc, literals,
+                          replicas=R)
+        return out.reshape(R, B, C, J), None
+    out, chosen = _per_device(kernel, inc, literals,
+                              u.reshape(D, B, C * J), replicas=R)
+    return out.reshape(R, B, C, J), chosen.reshape(R, B, C, J)
+
+
+def feedback_patch_replicated(
+    ta_state: jax.Array,    # [R, C, J, L]
+    literals: jax.Array,    # [R, C, J, L] — each clause's chosen patch
+    clause_out: jax.Array,  # [R, C, J]
+    type1_sel: jax.Array,   # [R, C, J]
+    type2_sel: jax.Array,   # [R, C, J]
+    u: jax.Array,           # [D, C, J, L] — replica r reads row r % D
+    *,
+    s: jax.Array,           # [R] f32 (scalars broadcast)
+    n_states: int,
+    s_policy: str,
+    boost_true_positive: bool,
+    interpret: bool,
+) -> jax.Array:
+    """Same contract as ref.feedback_patch_replicated: R TA banks, ONE
+    launch of the fused Pallas plane with a literal row per clause."""
+    R, C, J, L = ta_state.shape
+    D = u.shape[0]
+    s = jnp.broadcast_to(jnp.asarray(s, dtype=jnp.float32), (R,))
+    p_strengthen = jnp.where(boost_true_positive, 1.0, (s - 1.0) / s)
+    p_erase = (1.0 / s) if s_policy == "standard" else (s - 1.0) / s
+    out = _per_device(
+        partial(_fb.feedback_patch_replicated, n_states=n_states,
+                interpret=interpret),
+        ta_state.reshape(R, C * J, L),
+        literals.reshape(R, C * J, L),
+        clause_out.reshape(R, C * J),
+        type1_sel.reshape(R, C * J),
+        type2_sel.reshape(R, C * J),
+        u.reshape(D, C * J, L),
+        p_strengthen,
+        jnp.asarray(p_erase, dtype=jnp.float32),
+        replicas=R,
+    )
+    return out.reshape(R, C, J, L)

@@ -341,8 +341,20 @@ def feedback_step_replicated(
     p_strengthen = jnp.where(boost_true_positive, 1.0, (s - 1.0) / s)
     p_erase = (1.0 / s) if s_policy == "standard" else (s - 1.0) / s
 
+    return _update_replicated(
+        ta_state, literals[None, :, None, None, :], clause_out, type1_sel,
+        type2_sel, u, p_strengthen, p_erase, H=H, n_states=n_states,
+    )
+
+
+def _update_replicated(ta_state, lit, clause_out, type1_sel, type2_sel, u,
+                       p_strengthen, p_erase, *, H: int, n_states: int):
+    """The fused Type I/II plane of R = H x D banks; ``lit`` broadcasts
+    against ``[H, D, C, J, L]`` (one literal row per data stream, or one
+    per clause)."""
+    R, C, J, L = ta_state.shape
+    D = R // H
     ta = ta_state.reshape(H, D, C, J, L)
-    lit = literals[None, :, None, None, :]
     uB = u[None]
     c_out = clause_out.reshape(H, D, C, J)[..., None]
     t1 = type1_sel.reshape(H, D, C, J)[..., None]
@@ -363,3 +375,87 @@ def feedback_step_replicated(
     delta = jnp.where(t1, d1, 0) + jnp.where(t2, d2, 0)
     new_state = jnp.clip(ta.astype(acc_dtype) + delta, 1, 2 * n_states)
     return new_state.reshape(R, C, J, L).astype(ta_state.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Patch machines: the Convolutional TM (Granmo et al., arXiv:1905.09688)
+# ---------------------------------------------------------------------------
+
+
+def patch_clause_eval_replicated(
+    include: jax.Array,        # [R, C, J, L] bool (post-fault TA actions)
+    literals: jax.Array,       # [D, B, P, L] bool — replica r reads batch r % D
+    u: jax.Array | None = None,  # [D, B, C, J] f32 — one uniform per clause
+    *,
+    training: bool,
+) -> tuple[jax.Array, jax.Array | None]:
+    """Patch clause eval: (clause_out [R, B, C, J] bool, chosen [R, B, C, J]
+    i32, or None without ``u``).
+
+    A clause's conjunction on patch p follows the plain rule: it fires iff
+    every included literal of p is 1. The clause output is the OR over the
+    P patches. An empty clause fires on every patch while training and is
+    silent at inference.
+
+    With ``u``: m is the number of patches the clause fired on, and
+    ``chosen`` is the ``min(floor(u * m), m - 1)``-th of them in patch
+    order (counting from 0): the patch whose literals take the clause's
+    feedback. It is 0 when m = 0, where no patch is used.
+
+        violations[p, cj] = sum_l (1 - literal[p, l]) * include[cj, l]
+
+    Counts are integers <= L << 2^24, so the f32 contraction is exact.
+    """
+    R, C, J, L = include.shape
+    D, B, P, _ = literals.shape
+    if R % D:
+        raise ValueError(f"data replicas {D} must divide replicas {R}")
+    H, CJ = R // D, C * J
+    inc = include.reshape(H, D, CJ, L).astype(jnp.float32)
+    neg = 1.0 - literals.astype(jnp.float32)              # [D, B, P, L]
+    viol = jnp.einsum("dbpl,hdkl->hdbpk", neg, inc)       # [H, D, B, P, CJ]
+    fired = viol == 0
+    out = jnp.any(fired, axis=-2)                         # [H, D, B, CJ]
+    if not training:
+        empty = ~jnp.any(include, axis=-1).reshape(H, D, 1, CJ)
+        out = out & ~empty
+    out = out.reshape(R, B, C, J)
+    if u is None:
+        return out, None
+    m = jnp.sum(fired, axis=-2, dtype=jnp.int32)          # [H, D, B, CJ]
+    t = jnp.floor(u.reshape(1, D, B, CJ) * m.astype(jnp.float32))
+    t = jnp.minimum(t.astype(jnp.int32), m - 1)
+    cum = jnp.cumsum(fired, axis=-2, dtype=jnp.int32)     # inclusive counts
+    chosen = jnp.sum(cum <= t[..., None, :], axis=-2, dtype=jnp.int32)
+    return out, chosen.reshape(R, B, C, J)
+
+
+def feedback_patch_replicated(
+    ta_state: jax.Array,    # [R, C, J, L] int8/int16 (pre-update)
+    literals: jax.Array,    # [R, C, J, L] bool — each clause's chosen patch
+    clause_out: jax.Array,  # [R, C, J] bool
+    type1_sel: jax.Array,   # [R, C, J] bool
+    type2_sel: jax.Array,   # [R, C, J] bool
+    u: jax.Array,           # [D, C, J, L] f32 — replica r reads row r % D
+    *,
+    s: jax.Array,           # [R] f32 (scalars broadcast)
+    n_states: int,
+    s_policy: str,
+    boost_true_positive: bool,
+) -> jax.Array:
+    """:func:`feedback_step_replicated` with one literal row per clause:
+    a patch machine's clause takes feedback from the literals of the patch
+    it chose (:func:`patch_clause_eval_replicated`). MUST equal that entry
+    on banks whose clauses all chose literal rows equal to the row given."""
+    R, C, J, L = ta_state.shape
+    D = u.shape[0]
+    if R % D:
+        raise ValueError(f"data replicas {D} must divide replicas {R}")
+    H = R // D
+    s = jnp.broadcast_to(jnp.asarray(s, jnp.float32), (R,)).reshape(H, D, 1, 1, 1)
+    p_strengthen = jnp.where(boost_true_positive, 1.0, (s - 1.0) / s)
+    p_erase = (1.0 / s) if s_policy == "standard" else (s - 1.0) / s
+    return _update_replicated(
+        ta_state, literals.reshape(H, D, C, J, L), clause_out, type1_sel,
+        type2_sel, u, p_strengthen, p_erase, H=H, n_states=n_states,
+    )

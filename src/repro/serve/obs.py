@@ -6,8 +6,10 @@ One small system, three parts:
   decorated with ``jax.named_scope(FLUSH | DRAIN)``, so every HLO op they
   compile to carries ``tm.flush`` (the ingress enqueue) or ``tm.drain``
   (the drain step and its key split) in its ``op_name`` metadata, which an
-  HLO dump and any profiler that reads that metadata group ops by. A scope
-  changes metadata only, never the computation.
+  HLO dump and any profiler that reads that metadata group ops by; a patch
+  machine's patch literals and patch evaluation carry ``tm.patch``
+  (``core/tm.py``) inside them and inside the analysis. A scope changes
+  metadata only, never the computation.
 * **Host spans** (``tm.flush``, ``tm.drain``, ``tm.analysis``,
   ``tm.policy``), recorded only while a ``jax.profiler`` trace is
   recording. Each one then also enters ``jax.profiler.TraceAnnotation
@@ -21,7 +23,10 @@ One small system, three parts:
   ``flush.rows`` (rows landed in device buffers), ``flush.slots`` (plane
   length x ingress block per enqueue dispatch: the staging slots it
   carries, filled or not), ``residency.activations`` /
-  ``residency.evictions``, and the process-wide ``jit.traces`` /
+  ``residency.evictions``, ``patch.evals.<pass>`` (a patch machine's row
+  slots x patches put through patch evaluation, counted at dispatch from
+  the planes' shapes, for the ``train`` and ``monitor`` passes of the
+  drain and the ``analysis`` pass), and the process-wide ``jit.traces`` /
   ``jit.compile_s`` (jaxpr traces and backend-compile seconds per
   function name, from ``jax.monitoring``).
   Every jaxpr trace's time is also kept, so :func:`traces_between` counts
@@ -47,6 +52,10 @@ DRAIN = "tm.drain"
 # a host FSM with an eager select, so neither has a jitted body to scope
 ANALYSIS = "tm.analysis"
 POLICY = "tm.policy"
+# a patch machine's counter (DESIGN.md §18): row slots x patches put
+# through patch evaluation, one key per pass
+PATCH_EVALS = "patch.evals"
+PATCH_PASSES = ("train", "monitor", "analysis")
 
 # spans and jaxpr-trace times kept in memory; the oldest drop first (a
 # traced benchmark window records a few dozen spans a second)
@@ -174,7 +183,8 @@ class Obs:
         self._stack: list = []
         self._counts = {"flush.rows": 0, "flush.slots": 0,
                         "residency.activations": 0,
-                        "residency.evictions": 0}
+                        "residency.evictions": 0,
+                        **{f"{PATCH_EVALS}.{p}": 0 for p in PATCH_PASSES}}
 
     def span(self, name: str):
         """A host span; a no-op unless a profiler trace is recording."""

@@ -356,6 +356,12 @@ class TMService:
         self.chunk = max(1, min(sc.chunk, sc.buffer_capacity))
         self.mesh = sc.mesh
         self.policy = sc.policy
+        if cfg.patched and (residency or sc.tunable is not None):
+            raise ValueError(
+                "patch machines (TMConfig.window) run without residency "
+                "(resident < replicas) and without the tunable serve path "
+                "for now; see DESIGN.md §18"
+            )
         if residency and (jnp.ndim(self.rt.s) != 0
                           or jnp.ndim(self.rt.T) != 0):
             raise ValueError(
@@ -372,7 +378,8 @@ class TMService:
         # single-machine drain/inference bodies (DESIGN.md §10: the
         # replicated plane costs ~1.3x at R = 1); pinned bitwise against
         # the replicated path by the parity suites.
-        self._k1 = (K == 1 and self.mesh is None
+        # Patch machines take the replicated bodies at every K.
+        self._k1 = (K == 1 and self.mesh is None and not cfg.patched
                     and jnp.ndim(self.rt.s) == 0 and jnp.ndim(self.rt.T) == 0)
 
         seed = sc.seed
@@ -1016,6 +1023,9 @@ class TMService:
                 self.cfg, self.chunk, self._ss, self.rt,
                 jnp.asarray(want), chunk_keys, monitor=monitor,
             )
+            self._count_patch_evals("train", K * self.chunk)
+            if monitor:
+                self._count_patch_evals("monitor", K * self.chunk)
             n = np.asarray(n, dtype=np.int64)
             trained += n
             with self.router.lock:
@@ -1024,6 +1034,13 @@ class TMService:
                 on_chunk(aux)
             active &= (n == want) & (trained < budget)
         return trained
+
+    def _count_patch_evals(self, pass_: str, row_slots: int) -> None:
+        """``patch.evals.<pass>``: row slots x patches put through patch
+        evaluation by a dispatch (patch machines only)."""
+        if self.cfg.patched:
+            self.obs.add(f"{obs_mod.PATCH_EVALS}.{pass_}",
+                         row_slots * self.cfg.n_patches)
 
     def _debit_mirror(self, n_plane) -> None:
         """Map a device-plane consumed-rows vector onto the [K] mirror
@@ -1303,6 +1320,8 @@ class TMService:
             self.cfg, self._ss.tm, self.rt,
             self.eval_x[None], self.eval_y[None],  # D = 1: shared
         ))
+        self._count_patch_evals("analysis",
+                                len(acc_p) * self.eval_x.shape[0])
         if self._res is None:
             return acc_p
         acc = np.full(self.n_replicas, np.nan, dtype=np.float32)
@@ -1502,6 +1521,7 @@ class TMService:
         fleet: the checkpoint is residency-agnostic and restores under
         any ``resident`` budget (migration across device budgets).
         Returns the checkpoint path."""
+        self._refuse_patched_checkpoint()
         with self._device():
             self.flush()
             ss_K, keys_K = self._assemble_plane()
@@ -1555,6 +1575,11 @@ class TMService:
             return ckpt_mod.save(directory, int(step), tree, keep=keep,
                                  extra=extra)
 
+    def _refuse_patched_checkpoint(self) -> None:
+        if self.cfg.patched:
+            raise ValueError("checkpoints of patch machines (TMConfig.window) "
+                             "are not supported yet; see DESIGN.md §18")
+
     def _service_manifest(self) -> dict:
         """JSON-able construction knobs — enough for :meth:`restore` to
         rebuild the service without the caller knowing them."""
@@ -1591,6 +1616,7 @@ class TMService:
         replicas, capacity, packing — :meth:`restore` guarantees that);
         the ``resident`` budget may differ. Anything staged or held now
         is discarded: the checkpoint defines the complete state."""
+        self._refuse_patched_checkpoint()
         with self._device():
             # settle pending spills BEFORE the install clears the store —
             # a stale deferred snapshot must never land in the fresh one

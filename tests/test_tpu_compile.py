@@ -2,7 +2,9 @@
 
 The Pallas kernels of the main path and the packed fleet drain compile at
 the f=784 preset (``configs/tm_mnist.py``: 10 classes x 64 clauses x 1568
-literals) with interpret mode off, for one chip and for a 2x2 mesh. The
+literals) with interpret mode off, for one chip and for a 2x2 mesh; the
+patch entries at the Convolutional TM's widths (361 patches of 272
+literals, 10 x 2,000 clauses). The
 compiler refuses here what a chip would refuse: unaligned tiles, too much
 fast memory, a program that does not fit HBM, a kernel GSPMD cannot
 partition. Nothing runs, so nothing here is a result or a time.
@@ -23,13 +25,17 @@ from repro.configs import tm_mnist
 from repro.core import init_runtime
 from repro.core import online as online_mod
 from repro.core.online import SessionState
-from repro.core.tm import TMState
+from repro.core.tm import TMConfig, TMState
 from repro.data import buffer as buf_mod
 from repro.distributed import sharding as shard_mod
 from repro.kernels import dispatch, packing
 
 CFG = tm_mnist.CONFIG.tm
 C, J, L = CFG.max_classes, CFG.max_clauses, CFG.n_literals
+# the patch entries at the Convolutional TM's widths (bench/configs/
+# ctm-digits28-w10.json): 28x28 images, 10x10 window, 361 patches
+CTM = TMConfig(n_features=784, max_classes=10, max_clauses=2000, n_states=128,
+               image=(28, 28), window=(10, 10))
 W = 2 * packing.n_words(CFG.n_features)   # packed include/literal words
 B, R, M = 64, 8, 32                        # batch, replicas (D = R), budget
 HBM_BYTES = 16 * 10**9                     # one v5e chip
@@ -78,6 +84,17 @@ def _sds(shape, dtype, sharding):
 
 def _entry_operands(name: str):
     """(positional operand shapes+dtypes, static kwargs) of one entry."""
+    if "patch" in name:
+        bank = (R, CTM.max_classes, CTM.max_clauses, CTM.n_literals)
+        if name.startswith("feedback"):
+            return ([(bank, CTM.state_dtype), (bank, jnp.bool_)]
+                    + [(bank[:3], jnp.bool_)] * 3
+                    + [(bank, jnp.float32), ((R,), jnp.float32)],
+                    dict(n_states=CTM.n_states, s_policy=CTM.s_policy,
+                         boost_true_positive=CTM.boost_true_positive))
+        return ([(bank, jnp.bool_),
+                 ((R, 1, CTM.n_patches, CTM.n_literals), jnp.bool_),
+                 ((R, 1) + bank[1:3], jnp.float32)], dict(training=True))
     rep = "replicated" in name
     packed = name.endswith("packed")
     inc = ((R,) if rep else ()) + (C, J) + ((W,) if packed else (L,))
