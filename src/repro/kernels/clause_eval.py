@@ -49,6 +49,22 @@ def _pad_l(L: int) -> tuple[int, int]:
     return -(-L // blk) * blk, blk
 
 
+def lane_plane(cols, dtype, rows: int | None = None) -> jax.Array:
+    """Same-shaped ``[..., n]`` columns as the first lanes of a zero
+    ``[..., rows, LANES]`` plane (``rows`` defaults to ``n``), built in one
+    dense write: the columns stacked on a last axis and padded once.
+
+    The same plane built as ``zeros(...).at[..., :n, k].set(col)`` is one
+    scatter per column into a 128-lane tiled array, which a v5e pays for
+    nearly as much as a pass over the whole plane.
+    """
+    x = jnp.stack(cols, axis=-1).astype(dtype)
+    n = x.shape[-2]
+    rows = n if rows is None else rows
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                   + [(0, rows - n), (0, LANES - len(cols))])
+
+
 def _kernel(l_axis: int, inc_ref, rhs_ref, out_ref):
     # inc: [BLK_CJ, blk_l] int8, rhs: [blk_l, LANES] int8 -> accumulate
     # [BLK_CJ, LANES] i32 partial sums over the innermost (literal) axis.
@@ -90,9 +106,8 @@ def clause_counts(
         include.astype(jnp.int8)
     )
     # rhs col 0: ~literal (violation counter); col 1: ones (include counter).
-    rhs = jnp.zeros((Lp, LANES), dtype=jnp.int8)
-    rhs = rhs.at[:L, 0].set(1 - literals.astype(jnp.int8))
-    rhs = rhs.at[:L, 1].set(1)
+    lit = literals.astype(jnp.int8)
+    rhs = lane_plane([1 - lit, jnp.ones_like(lit)], jnp.int8, rows=Lp)
 
     out = pl.pallas_call(
         functools.partial(_kernel, 1),
@@ -214,9 +229,8 @@ def clause_counts_replicated(
     inc = jnp.zeros((R, cjp, Lp), dtype=jnp.int8).at[:, :cj, :L].set(
         include.astype(jnp.int8)
     )
-    rhs = jnp.zeros((D, Lp, LANES), dtype=jnp.int8)
-    rhs = rhs.at[:, :L, 0].set(1 - literals.astype(jnp.int8))
-    rhs = rhs.at[:, :L, 1].set(1)
+    lit = literals.astype(jnp.int8)
+    rhs = lane_plane([1 - lit, jnp.ones_like(lit)], jnp.int8, rows=Lp)
 
     out = pl.pallas_call(
         functools.partial(_kernel_replicated, 2),

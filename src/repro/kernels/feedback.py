@@ -6,10 +6,12 @@ TA states are read+written exactly once per datapoint and no [CJ, L]
 intermediates (deltas, masks) ever round-trip to HBM.
 
 Layout: rows = flattened (class, clause); lanes = literals. Per-clause
-control (clause output, Type I/II selection) is packed into the first three
-columns of a [CJ, LANES] int8 control block so every operand block is
-TPU-tile aligned; probabilities ride a [1, LANES] f32 vector (col 0 =
-p_strengthen, col 1 = p_erase) and broadcast inside the kernel.
+control (clause output, Type I/II selection) rides the first three lanes
+of a [CJ, LANES] int8 control block so every operand block is TPU-tile
+aligned; probabilities ride a [1, LANES] f32 vector (lane 0 =
+p_strengthen, lane 1 = p_erase) and broadcast inside the kernel. Both are
+built in one dense write each (``clause_eval.lane_plane``), never as
+column scatters, which a v5e pays for as whole-plane passes.
 
 At MNIST-scale widths the literal axis is tiled too (``BLK_L`` lanes per
 block, the same scheme as ``clause_eval.py``): the update is elementwise
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.clause_eval import _pad_l
+from repro.kernels.clause_eval import _pad_l, lane_plane
 
 BLK_CJ = 32
 LANES = 128
@@ -113,16 +115,13 @@ def feedback_plane(
     lit = jnp.zeros((1, Lp), dtype=jnp.int8).at[0, :L].set(
         literals.astype(jnp.int8)
     )
-    ctl = jnp.zeros((cjp, LANES), dtype=jnp.int8)
-    ctl = ctl.at[:cj, 0].set(clause_out.astype(jnp.int8))
-    ctl = ctl.at[:cj, 1].set(type1_sel.astype(jnp.int8))
-    ctl = ctl.at[:cj, 2].set(type2_sel.astype(jnp.int8))
+    ctl = lane_plane([clause_out, type1_sel, type2_sel], jnp.int8, rows=cjp)
     # Pad u with 1.0 so padded lanes never draw an action.
     up = jnp.ones((cjp, Lp), dtype=jnp.float32).at[:cj, :L].set(
         u.astype(jnp.float32)
     )
-    p = jnp.zeros((1, LANES), dtype=jnp.float32)
-    p = p.at[0, 0].set(p_strengthen).at[0, 1].set(p_erase)
+    p = lane_plane([jnp.reshape(p_strengthen, (1,)),
+                    jnp.reshape(p_erase, (1,))], jnp.float32)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_states),
@@ -182,17 +181,12 @@ def feedback_plane_replicated(
     lit = jnp.zeros((D, 1, Lp), dtype=jnp.int8).at[:, 0, :L].set(
         literals.astype(jnp.int8)
     )
-    ctl = jnp.zeros((R, cjp, LANES), dtype=jnp.int8)
-    ctl = ctl.at[:, :cj, 0].set(clause_out.astype(jnp.int8))
-    ctl = ctl.at[:, :cj, 1].set(type1_sel.astype(jnp.int8))
-    ctl = ctl.at[:, :cj, 2].set(type2_sel.astype(jnp.int8))
+    ctl = lane_plane([clause_out, type1_sel, type2_sel], jnp.int8, rows=cjp)
     # Pad u with 1.0 so padded lanes never draw an action.
     up = jnp.ones((D, cjp, Lp), dtype=jnp.float32).at[:, :cj, :L].set(
         u.astype(jnp.float32)
     )
-    p = jnp.zeros((R, 1, LANES), dtype=jnp.float32)
-    p = p.at[:, 0, 0].set(p_strengthen.astype(jnp.float32))
-    p = p.at[:, 0, 1].set(p_erase.astype(jnp.float32))
+    p = lane_plane([p_strengthen[:, None], p_erase[:, None]], jnp.float32)
 
     out = pl.pallas_call(
         functools.partial(_kernel_replicated, n_states),
@@ -245,15 +239,8 @@ def feedback_patch_replicated(
         raise ValueError(f"data replicas {D} must divide replicas {R}")
     blk = min(BLK_PCJ, -(-cj // BLK_CJ) * BLK_CJ)
 
-    # one padded write: three column updates of this 128-lane plane took
-    # about as long as the kernel itself on a v5e
-    ctl = jnp.pad(
-        jnp.stack([clause_out, type1_sel, type2_sel], axis=-1).astype(jnp.int8),
-        ((0, 0), (0, 0), (0, LANES - 3)),
-    )
-    p = jnp.zeros((R, 1, LANES), dtype=jnp.float32)
-    p = p.at[:, 0, 0].set(p_strengthen.astype(jnp.float32))
-    p = p.at[:, 0, 1].set(p_erase.astype(jnp.float32))
+    ctl = lane_plane([clause_out, type1_sel, type2_sel], jnp.int8)
+    p = lane_plane([p_strengthen[:, None], p_erase[:, None]], jnp.float32)
 
     plane = pl.BlockSpec((1, blk, L), lambda r, i: (r, i, 0))
     return pl.pallas_call(

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import dispatch, packing, ref
+from repro.kernels import clause_eval, dispatch, packing, ref
 
 SHAPES = [
     (1, 2, 5),      # degenerate
@@ -219,6 +219,45 @@ def test_feedback_replicated_matches_stacked(shape, policy, dtype, mod):
             ta, lits, c_out, t1, t2, u, s=s, **kw
         )
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+def _column_scatter_plane(cols, dtype, rows):
+    """The control planes as the kernels once built them: a zero
+    [..., rows, 128] plane and one lane-column scatter per column."""
+    n = cols[0].shape[-1]
+    plane = jnp.zeros(cols[0].shape[:-1] + (rows, 128), dtype)
+    for k, c in enumerate(cols):
+        plane = plane.at[..., :n, k].set(jnp.asarray(c).astype(dtype))
+    return plane
+
+
+@pytest.mark.parametrize("width", [(48, 32), (640, 1568), (37, 45)],
+                         ids=["iris", "f784", "odd"])
+@pytest.mark.parametrize("form", ["ctl", "p", "rhs"])
+@pytest.mark.parametrize("lead", [(), (3,)], ids=["single", "replicated"])
+def test_lane_plane_matches_column_scatters(width, form, lead):
+    """``lane_plane`` (one dense write) gives the plane of the column
+    scatters it replaced, bit for bit: the feedback kernel's ``ctl``
+    (clause_out, type1, type2 over CJ padded rows) and ``p`` (p_strengthen,
+    p_erase on one row), the clause count's ``rhs`` (1 - literal, 1 over
+    L padded rows)."""
+    cj, L = width
+    rng = np.random.default_rng(cj * 10_000 + L)
+    if form == "ctl":
+        cols = [jnp.asarray(rng.random(lead + (cj,)) < 0.5) for _ in range(3)]
+        dtype, rows = jnp.int8, -(-cj // clause_eval.BLK_CJ) * clause_eval.BLK_CJ
+    elif form == "p":
+        cols = [jnp.asarray(rng.random(lead + (1,)), jnp.float32)
+                for _ in range(2)]
+        dtype, rows = jnp.float32, 1
+    else:
+        lit = jnp.asarray(rng.random(lead + (L,)) < 0.5).astype(jnp.int8)
+        cols = [1 - lit, jnp.ones_like(lit)]
+        dtype, rows = jnp.int8, clause_eval._pad_l(L)[0]
+    want = _column_scatter_plane(cols, dtype, rows)
+    got = clause_eval.lane_plane(cols, dtype, rows=rows)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
 def test_feedback_replicated_rejects_bad_data_axis():

@@ -13,6 +13,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under pytest-xdist only the
 worker given this file does.
 """
+import re
 from functools import partial
 
 import jax
@@ -131,30 +132,84 @@ def test_kernel_entry_compiles_at_f784(topo, pallas, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _drain_args(sharding_of):
-    """Shapes of the packed drain's operands at K tenants, placed by
-    ``sharding_of(tree) -> tree of shardings``."""
+def _drain_args(sharding_of, cfg=CFG, k=K):
+    """Shapes of the packed drain's operands at ``k`` tenants of ``cfg``,
+    placed by ``sharding_of(tree) -> tree of shardings``."""
     buf = jax.eval_shape(
-        lambda: buf_mod.make(CAPACITY, CFG.n_features, packed=True))
+        lambda: buf_mod.make(CAPACITY, cfg.n_features, packed=True))
     ss = SessionState(
-        tm=TMState(ta_state=jax.ShapeDtypeStruct((K, C, J, L),
-                                                 CFG.state_dtype)),
+        tm=TMState(ta_state=jax.ShapeDtypeStruct(
+            (k, cfg.max_classes, cfg.max_clauses, cfg.n_literals),
+            cfg.state_dtype)),
         buf=jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct((K,) + a.shape, a.dtype), buf),
-        step=jax.ShapeDtypeStruct((K,), jnp.int32),
+            lambda a: jax.ShapeDtypeStruct((k,) + a.shape, a.dtype), buf),
+        step=jax.ShapeDtypeStruct((k,), jnp.int32),
     )
-    rt = jax.eval_shape(lambda: init_runtime(CFG, s=1.5, T=32))
-    limit = jax.ShapeDtypeStruct((K,), jnp.int32)
-    keys = jax.ShapeDtypeStruct((K, 2), jnp.uint32)
+    rt = jax.eval_shape(lambda: init_runtime(cfg, s=1.5, T=32))
+    limit = jax.ShapeDtypeStruct((k,), jnp.int32)
+    keys = jax.ShapeDtypeStruct((k, 2), jnp.uint32)
     tree = (ss, rt, limit, keys)
     return jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s), tree,
                         sharding_of(tree))
 
 
-def _compile_drain(args):
+def _compile_drain(args, cfg=CFG):
     ss, rt, limit, keys = args
     return online_mod._consume_many_replicated.lower(
-        CFG, CHUNK, ss, rt, limit, keys, monitor=False).compile()
+        cfg, CHUNK, ss, rt, limit, keys, monitor=False).compile()
+
+
+_HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+
+
+def _while_body_ops(hlo: str) -> list[str]:
+    """Instruction lines of every computation a ``while`` body reaches
+    (fusions and calls included) in an HLO module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = _HEADER.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    todo = [b for lines in comps.values() for line in lines
+            if " while(" in line
+            for b in re.findall(r"body=%?([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += [t for t in re.findall(r"%([\w.\-]+)", line) if t in comps]
+    return [line for c in seen for line in comps[c]]
+
+
+# the iris machine of bench/configs/tm-iris-paper-k4096.json
+IRIS = TMConfig(n_features=16, max_classes=3, max_clauses=16, n_states=16)
+
+
+@pytest.mark.parametrize("cfg", [IRIS, CFG], ids=["iris", "f784"])
+def test_drain_step_builds_kernel_control_planes_without_scatters(
+        topo, pallas, cfg):
+    """The scan step's control planes (feedback ``ctl`` and ``p``, clause
+    count ``rhs``) are dense writes: no dynamic-update-slice or scatter in
+    the drain's loop comes from the two kernels' operand preparation."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = _compile_drain(_drain_args(
+        lambda t: jax.tree.map(lambda _: one_chip, t), cfg=cfg, k=64), cfg=cfg)
+    body = _while_body_ops(compiled.as_text())
+    kernels = ("feedback_plane_replicated", "clause_counts_replicated")
+    assert any("custom-call(" in op and any(k in op for k in kernels)
+               for op in body)
+    bad = [op.strip()[:160] for op in body
+           if re.search(r" (dynamic-update-slice|scatter)\(", op)
+           and any(f"jit({k})" in op for k in kernels)]
+    assert not bad, bad
 
 
 def test_packed_drain_k256_compiles_and_fits_one_chip(topo, pallas):
