@@ -6,11 +6,13 @@ The TPU/JAX analogue measured here:
 * `tm_train_step`  — fused inference+feedback for ONE datapoint (all
   C x J x 2f TA lanes in parallel): wall time per datapoint + TA-updates/s.
 * `tm_infer_batch` — batch-first inference throughput (datapoints/s) on the
-  dispatched `clause_eval_batch` path (include bank read once per batch).
+  dispatched `clause_eval_batch_replicated` path at R = 1 (include bank
+  read once per batch).
 * `tm_infer_vmap`  — the legacy vmap-of-per-sample inference plane, kept as
   the baseline the batch path is tracked against (bitwise-equal predictions
   asserted every run).
-* `tm_online_drain` — chunked `online._consume_many` drain rate vs the
+* `tm_online_drain` — chunked drain rate of one machine (`OnlineSession`,
+  the fleet drain `online._consume_many_replicated` at K = 1) vs the
   one-jitted-call-per-datapoint serving loop it replaced.
 * `hpsearch_grid`  — the paper's goal (ii): a (s x T x orderings) grid as a
   single vmapped program vs. the same grid run sequentially; the speedup is
@@ -110,7 +112,7 @@ def main():
           datapoints_per_s=150 / dt_v, batched_speedup=speedup,
           bitwise_identical=True)
 
-    # --- online serving drain: chunked _consume_many vs per-point consume ---
+    # --- online serving drain: chunked drain vs per-point consume ---
     def drain(chunk):
         sess = online_mod.OnlineSession(
             CFG, st, rt, buffer_capacity=128, chunk=chunk, seed=0

@@ -46,8 +46,11 @@ def _selection_core(
     y: jax.Array,            # scalar int32 target class
     key: jax.Array,
 ):
-    """One replica's feedback-type selection (shared by the single-machine and
-    replica-parallel paths so both consume identical RNG streams)."""
+    """One replica's feedback-type selection: the target class y gets
+    P(feedback) = (T - clip(v_y)) / 2T (positive-polarity clauses Type I,
+    negative Type II); one sampled non-target class ny gets
+    P(feedback) = (T + clip(v_ny)) / 2T (positive Type II, negative
+    Type I)."""
     k_neg, k_t, k_n = jax.random.split(key, 3)
     Tf = T.astype(jnp.float32)
     C, J = cfg.max_classes, cfg.max_clauses
@@ -82,25 +85,6 @@ def _selection_core(
     return type1, type2
 
 
-def _feedback_selection(
-    cfg: TMConfig,
-    rt: TMRuntime,
-    votes: jax.Array,  # [C] int32
-    y: jax.Array,      # scalar int32 target class
-    key: jax.Array,
-):
-    """Choose per-clause feedback types for the target + one sampled non-target.
-
-    Target class y:   P(feedback) = (T - clip(v_y)) / 2T
-                      positive-polarity clauses -> Type I, negative -> Type II.
-    Sampled class ny: P(feedback) = (T + clip(v_ny)) / 2T
-                      positive -> Type II, negative -> Type I.
-    """
-    return _selection_core(
-        cfg, rt.T, rt.clause_mask, rt.class_mask, votes, y, key
-    )
-
-
 def train_update(
     cfg: TMConfig,
     state: TMState,
@@ -114,38 +98,18 @@ def train_update(
     The learning half of the paper's 2-clock-cycle datapath: one fused plane
     of (C x J x 2f) elementwise work plus two small reductions. Returns
     (new_state, training-mode votes [C], activity scalar). Consumers that
-    want per-step inference-mode monitoring use :func:`train_step`; batched
-    consumers (``online._consume_many``) hoist monitoring out of the serial
-    scan and run it once per chunk through the batch-first clause kernel.
+    want per-step inference-mode monitoring use :func:`train_step`; the
+    drain (``online._consume_many_replicated``) hoists monitoring out of
+    the serial scan and runs it once per chunk through the batch clause
+    kernel.
 
-    A patch machine runs as the R = D = 1 case of
-    :func:`train_update_replicated`, which holds its patch semantics.
+    One machine is the R = D = 1 slice of :func:`train_update_replicated`,
+    which splits ``key`` into the selection and uniform draws.
     """
-    if cfg.patched:
-        st, votes, activity = train_update_replicated(
-            cfg, TMState(ta_state=state.ta_state[None]), rt, x[None],
-            y[None], key[None])
-        return TMState(ta_state=st.ta_state[0]), votes[0], activity[0]
-    k_sel, k_u = jax.random.split(key)
-    lits = tm_mod.make_literals(x)
-    include = tm_mod.ta_actions(cfg, state, rt)
-
-    clauses_tr = tm_mod.eval_clauses(cfg, include, lits, rt, training=True)
-    votes = tm_mod.class_sums(cfg, clauses_tr)
-
-    type1, type2 = _feedback_selection(cfg, rt, votes, y, k_sel)
-    u = jax.random.uniform(
-        k_u, (cfg.max_classes, cfg.max_clauses, cfg.n_literals), dtype=jnp.float32
-    )
-
-    new_ta = dispatch.resolve(cfg.backend).feedback_step(
-        state.ta_state, lits, clauses_tr, type1, type2, u,
-        s=rt.s, n_states=cfg.n_states, s_policy=cfg.s_policy,
-        boost_true_positive=cfg.boost_true_positive,
-    )
-
-    activity = jnp.mean((new_ta != state.ta_state).astype(jnp.float32))
-    return TMState(ta_state=new_ta), votes, activity
+    st, votes, activity = train_update_replicated(
+        cfg, TMState(ta_state=state.ta_state[None]), rt, x[None],
+        y[None], key[None])
+    return TMState(ta_state=st.ta_state[0]), votes[0], activity[0]
 
 
 def train_step(
@@ -239,11 +203,12 @@ def train_update_replicated(
 ) -> tuple[TMState, jax.Array, jax.Array]:
     """One datapoint's TA-bank update for all R replicas at once.
 
-    Replica ``r`` performs exactly the computation of :func:`train_update`
-    with data stream ``r % D`` and hyperparameters ``s[r]``/``T[r]`` —
-    bit-for-bit, including the RNG draws (streams are keyed per data
-    replica, shared across a hyperparameter grid exactly as re-running
-    :func:`train_update` per cell would). Returns (new_state,
+    Replica ``r`` performs exactly the computation of one machine
+    (:func:`train_update`, the R = D = 1 slice) with data stream ``r % D``
+    and hyperparameters ``s[r]``/``T[r]`` — bit-for-bit, including the RNG
+    draws: ``key[d]`` splits into the feedback-selection and uniform keys,
+    so streams are keyed per data replica, shared across a hyperparameter
+    grid exactly as re-running :func:`train_update` per cell would. Returns (new_state,
     votes [R, C], activity [R]); unused outputs are DCE'd under jit.
 
     A patch machine (``cfg.patched``, the Convolutional TM) evaluates each

@@ -40,8 +40,9 @@ class SessionState(NamedTuple):
 class ChunkAux(NamedTuple):
     """Per-chunk observability from the drain.
 
-    Single-machine shapes below (chunk size K); the replica-first drain
-    returns the same fields with a LEADING replica axis ``[R, K]``.
+    One machine's shapes below (chunk size K), as ``OnlineSession``'s
+    callback sees them; the drain returns every field with a LEADING
+    replica axis ``[R, K]``.
     """
 
     predicted: jax.Array  # [K] int32 — batched inference under the post-chunk state
@@ -157,10 +158,12 @@ def _consume_many_replicated(
     the scan and done once per chunk as ONE replica-first batched clause
     contraction under the post-chunk states.
 
-    Replica ``r`` is bit-identical to running :func:`_consume_many` alone
-    with ``(ss[r], limit[r], keys[r])`` — the replicated kernels' stacking
-    guarantee plus per-replica RNG streams (split per chunk key exactly as
-    the single-machine path splits its one key).
+    Replica ``r`` is bit-identical to draining it alone (R = 1) with
+    ``(ss[r], limit[r], keys[r])`` — the replicated kernels' stacking
+    guarantee plus per-replica RNG streams: each chunk key splits into
+    ``k`` step keys, and step ``i``'s row trains as
+    ``feedback.train_update`` with step key ``i``. One machine drains as
+    the R = 1 plane of this function.
 
     PACKED buffers (uint32 rows, DESIGN.md §13) are transparent here: each
     popped row unpacks once for the elementwise TA feedback (pack/unpack is
@@ -211,77 +214,6 @@ def _consume_many_replicated(
     return SessionState(tm=tm, buf=buf, step=ss.step + n), n, aux
 
 
-@partial(jax.jit, static_argnums=(0, 1), static_argnames=("monitor",))
-@jax.named_scope("tm.drain")   # repro.serve.obs.DRAIN; core imports no serve
-def _consume_many(
-    cfg: TMConfig,
-    k: int,                 # static chunk size (one trace per chunk size)
-    ss: SessionState,
-    rt: TMRuntime,
-    limit: jax.Array,       # traced i32 — consume at most this many rows
-    key: jax.Array,
-    *,
-    monitor: bool = True,   # static: False skips the monitoring pass (aux=None)
-) -> tuple[SessionState, jax.Array, Optional[ChunkAux]]:
-    """Drain up to ``min(k, limit, buffered)`` datapoints in ONE jitted call.
-
-    The TA updates keep the FPGA's serial row-order semantics (``lax.scan``:
-    feedback at step t sees state from t-1), but the per-datapoint
-    inference-mode monitoring that :func:`~repro.core.feedback.train_step`
-    would run inside the loop is hoisted out and done once per chunk as a
-    batch-first clause eval under the post-chunk state — the include bank is
-    read K times for learning (inherent to serial semantics) and once, not K
-    times, for monitoring.
-
-    This is semantically the R = 1 slice of :func:`_consume_many_replicated`
-    (the fleet drain), but keeps a specialized single-machine body: the
-    replicated plane's per-step vmapped pop / key-split / gather machinery
-    is pure overhead at R = 1 (~1.3x on the compiled chunk: 20.0 vs 25.7
-    us/point, best-of-30 A/B on the iris machine). The two
-    implementations are pinned bitwise against each other by the K = 1
-    fleet parity suite (tests/test_fleet.py), which is a stronger check
-    than sharing the body would be.
-
-    PACKED buffers (uint32 rows, §13): popped rows unpack once for the
-    elementwise feedback; the hoisted monitoring pass stays packed (dtype
-    routing in ``predict_batch_``). Bit-identical to the unpacked drain.
-    """
-    limit = jnp.asarray(limit, dtype=jnp.int32)
-    packed = ss.buf.data_x.dtype == jnp.uint32          # static at trace time
-
-    def body(carry, inp):
-        buf, tm, n = carry
-        i, kk = inp
-        new_buf, x, y, nonempty = buf_mod.pop(buf)
-        valid = (i < limit) & nonempty
-        xb = packing.unpack_bits(x, cfg.n_features) if packed else x
-        new_tm, _, activity = fb_mod.train_update(cfg, tm, rt, xb, y, kk)
-        tm = jax.tree.map(lambda a, b: jnp.where(valid, a, b), new_tm, tm)
-        buf = jax.tree.map(lambda a, b: jnp.where(valid, a, b), new_buf, buf)
-        n = n + valid.astype(jnp.int32)
-        return (buf, tm, n), (x, y, valid, jnp.where(valid, activity, 0.0))
-
-    idx = jnp.arange(k, dtype=jnp.int32)
-    keys = jax.random.split(key, k)
-    (buf, tm, n), (xs, ys, valids, activity) = jax.lax.scan(
-        body, (ss.buf, ss.tm, jnp.int32(0)), (idx, keys)
-    )
-
-    # Hoisted monitoring: one batched inference pass over the chunk. Skipped
-    # entirely (not just discarded — a jitted return value can't be DCE'd)
-    # when the caller doesn't want it.
-    aux = None
-    if monitor:
-        preds = tm_mod.predict_batch_(cfg, tm, rt, xs)
-        aux = ChunkAux(
-            predicted=preds.astype(jnp.int32),
-            correct=(preds == ys) & valids,
-            valid=valids,
-            activity=activity,
-        )
-    return SessionState(tm=tm, buf=buf, step=ss.step + n), n, aux
-
-
 class OnlineSession:
     """Host-side driver for interleaved inference + online learning.
 
@@ -291,10 +223,10 @@ class OnlineSession:
     * ``infer(xs)``        — batched inference at any time.
 
     Since the TMService redesign this is a compatibility shim: the K = 1
-    slice of :class:`repro.serve.service.TMService` (which keeps the
-    specialized single-machine drain body on this slice), exposing the
-    historical scalar-shaped ``ss``/``step``/aux views. Pinned bitwise to
-    the pre-redesign implementation by tests/test_service.py.
+    slice of :class:`repro.serve.service.TMService` (the fleet drain at
+    R = 1), exposing the historical scalar-shaped ``ss``/``step``/aux
+    views. Pinned bitwise to the pre-redesign implementation by
+    tests/test_service.py.
     """
 
     def __init__(

@@ -274,6 +274,11 @@ def patch_training_clauses(
     return out[:, 0] & rt.clause_mask, picked
 
 
+def _fleet_of_one(state: TMState) -> TMState:
+    """A single machine's state as the R = 1 plane of a fleet."""
+    return TMState(ta_state=state.ta_state[None])
+
+
 def _refuse_patched(cfg: TMConfig, what: str) -> None:
     if cfg.patched:
         raise ValueError(f"{what} is not supported for patch machines "
@@ -322,15 +327,16 @@ def eval_clauses(
     *,
     training: bool,
 ) -> jax.Array:
-    """Clause outputs [C, J] bool.
+    """Clause outputs [C, J] bool: the R = D = 1 slice of the kernel
+    contract's ``clause_eval_replicated``.
 
     A clause fires iff every included literal is 1. Empty clauses output 1
     during training (so Type I feedback can grow them) and 0 during inference
     (standard TM convention; the paper inherits it from [5]).
     """
-    out = dispatch.resolve(cfg.backend).clause_eval(
-        include, literals, training=training
-    )
+    out = dispatch.resolve(cfg.backend).clause_eval_replicated(
+        include[None], literals[None], training=training
+    )[0]
     return out & rt.clause_mask[None, :]
 
 
@@ -342,14 +348,15 @@ def eval_clauses_batch(
     *,
     training: bool,
 ) -> jax.Array:
-    """Batch-first clause outputs [B, C, J] bool.
+    """Batch-first clause outputs [B, C, J] bool: the R = D = 1 slice of
+    ``clause_eval_batch_replicated``.
 
     The include bank is streamed once per batch (not once per datapoint);
     semantics are row-wise identical to :func:`eval_clauses`.
     """
-    out = dispatch.resolve(cfg.backend).clause_eval_batch(
-        include, literals, training=training
-    )
+    out = dispatch.resolve(cfg.backend).clause_eval_batch_replicated(
+        include[None], literals[None], training=training
+    )[0]
     return out & rt.clause_mask[None, None, :]
 
 
@@ -364,11 +371,12 @@ def eval_clauses_batch_packed(
     """Batch-first clause outputs [B, C, J] bool from the packed datapath.
 
     Bit-identical to :func:`eval_clauses_batch` on the corresponding
-    unpacked operands (the kernel contract's packed parity guarantee).
+    unpacked operands (the kernel contract's packed parity guarantee); the
+    R = D = 1 slice of ``clause_eval_batch_replicated_packed``.
     """
-    out = dispatch.resolve(cfg.backend).clause_eval_batch_packed(
-        include_packed, literals_packed, training=training
-    )
+    out = dispatch.resolve(cfg.backend).clause_eval_batch_replicated_packed(
+        include_packed[None], literals_packed[None], training=training
+    )[0]
     return out & rt.clause_mask[None, None, :]
 
 
@@ -390,16 +398,11 @@ def forward(
     *,
     training: bool = False,
 ):
-    """One datapoint through the datapath. Returns (clause_out [C,J], votes [C])."""
-    if cfg.patched:
-        clauses, votes = forward_batch_replicated(
-            cfg, TMState(ta_state=state.ta_state[None]), rt, x[None, None],
-            training=training)
-        return clauses[0, 0], votes[0, 0]
-    lits = make_literals(x)
-    include = ta_actions(cfg, state, rt)
-    clauses = eval_clauses(cfg, include, lits, rt, training=training)
-    return clauses, class_sums(cfg, clauses)
+    """One datapoint through the datapath. Returns (clause_out [C,J], votes [C]).
+
+    The one-row batch of :func:`forward_batch`."""
+    clauses, votes = forward_batch(cfg, state, rt, x[None], training=training)
+    return clauses[0], votes[0]
 
 
 def forward_batch(
@@ -418,23 +421,13 @@ def forward_batch(
     (buffer-fed monitoring, packed serving/analysis) route to the
     AND+popcount kernels with no call-site changes. Outputs are
     bit-identical across the two routes.
+
+    One machine is the R = D = 1 slice of :func:`forward_batch_replicated`,
+    patch machines included.
     """
-    if cfg.patched:
-        clauses, votes = forward_batch_replicated(
-            cfg, TMState(ta_state=state.ta_state[None]), rt, xs[None],
-            training=training)
-        return clauses[0], votes[0]
-    if xs.dtype == jnp.uint32:
-        lits = make_literals_packed(xs, cfg.n_features)
-        include = ta_actions_packed(cfg, state, rt)
-        clauses = eval_clauses_batch_packed(
-            cfg, include, lits, rt, training=training
-        )
-    else:
-        lits = make_literals(xs)
-        include = ta_actions(cfg, state, rt)
-        clauses = eval_clauses_batch(cfg, include, lits, rt, training=training)
-    return clauses, class_sums(cfg, clauses)
+    clauses, votes = forward_batch_replicated(
+        cfg, _fleet_of_one(state), rt, xs[None], training=training)
+    return clauses[0], votes[0]
 
 
 def predict(cfg: TMConfig, state: TMState, rt: TMRuntime, x: jax.Array) -> jax.Array:
@@ -447,10 +440,10 @@ def predict(cfg: TMConfig, state: TMState, rt: TMRuntime, x: jax.Array) -> jax.A
 def predict_batch_(
     cfg: TMConfig, state: TMState, rt: TMRuntime, xs: jax.Array
 ) -> jax.Array:
-    """Unjitted batch-first prediction [B] (composable inside other jits)."""
-    _, votes = forward_batch(cfg, state, rt, xs, training=False)
-    votes = jnp.where(rt.class_mask[None, :], votes, jnp.iinfo(jnp.int32).min)
-    return jnp.argmax(votes, axis=-1)
+    """Unjitted batch-first prediction [B] (composable inside other jits):
+    the R = D = 1 slice of :func:`predict_batch_replicated_`."""
+    return predict_batch_replicated_(cfg, _fleet_of_one(state), rt,
+                                     xs[None])[0]
 
 
 @partial(jax.jit, static_argnums=0)
@@ -460,8 +453,9 @@ def predict_batch(
     """Batch-first inference over a batch of datapoints (the serving path).
 
     The clause plane for all B datapoints is one dispatched
-    ``clause_eval_batch`` call — the include bank is read once per batch —
-    rather than a vmap of per-sample :func:`predict` planes.
+    ``clause_eval_batch_replicated`` call at R = 1 — the include bank is
+    read once per batch — rather than a vmap of per-sample
+    :func:`predict` planes.
     """
     return predict_batch_(cfg, state, rt, xs)
 
@@ -569,35 +563,24 @@ def forward_batch_pruned(
     ``sel`` a full permutation and unit weights the int32 vote sums are
     term-for-term a reordering of :func:`forward_batch`'s — bitwise
     identical votes, hence bitwise identical predictions.
+
+    The R = D = 1 slice of :func:`forward_batch_pruned_replicated`.
     """
-    _refuse_patched(cfg, "budgeted (pruned) inference")
-    kb = dispatch.resolve(cfg.backend)
-    if xs.dtype == jnp.uint32:
-        lits = make_literals_packed(xs, cfg.n_features)
-        include = ta_actions_packed(cfg, state, rt)
-        clauses = kb.clause_eval_batch_pruned_packed(
-            include, sel, lits, training=False
-        )
-    else:
-        lits = make_literals(xs)
-        include = ta_actions(cfg, state, rt)
-        clauses = kb.clause_eval_batch_pruned(
-            include, sel, lits, training=False
-        )                                                  # [B, C, M]
-    swt = vote_weights(cfg, rt, weights)                   # [C, J]
-    wsel = jnp.take_along_axis(swt, sel, axis=-1)          # [C, M]
-    votes = jnp.sum(clauses.astype(jnp.int32) * wsel[None], axis=-1)
-    return clauses, votes
+    clauses, votes = forward_batch_pruned_replicated(
+        cfg, _fleet_of_one(state), rt, xs[None], sel[None],
+        None if weights is None else weights[None])
+    return clauses[0], votes[0]
 
 
 def predict_batch_pruned_(
     cfg: TMConfig, state: TMState, rt: TMRuntime, xs: jax.Array,
     sel: jax.Array, weights: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Unjitted budgeted prediction [B] (composable inside other jits)."""
-    _, votes = forward_batch_pruned(cfg, state, rt, xs, sel, weights)
-    votes = jnp.where(rt.class_mask[None, :], votes, jnp.iinfo(jnp.int32).min)
-    return jnp.argmax(votes, axis=-1)
+    """Unjitted budgeted prediction [B] (composable inside other jits):
+    the R = D = 1 slice of :func:`predict_batch_pruned_replicated_`."""
+    return predict_batch_pruned_replicated_(
+        cfg, _fleet_of_one(state), rt, xs[None], sel[None],
+        None if weights is None else weights[None])[0]
 
 
 @partial(jax.jit, static_argnums=0)

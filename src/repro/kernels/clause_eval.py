@@ -65,18 +65,6 @@ def lane_plane(cols, dtype, rows: int | None = None) -> jax.Array:
                    + [(0, rows - n), (0, LANES - len(cols))])
 
 
-def _kernel(l_axis: int, inc_ref, rhs_ref, out_ref):
-    # inc: [BLK_CJ, blk_l] int8, rhs: [blk_l, LANES] int8 -> accumulate
-    # [BLK_CJ, LANES] i32 partial sums over the innermost (literal) axis.
-    @pl.when(pl.program_id(l_axis) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += jnp.dot(
-        inc_ref[...], rhs_ref[...], preferred_element_type=jnp.int32
-    )
-
-
 def _kernel_replicated(l_axis: int, inc_ref, rhs_ref, out_ref):
     # Leading length-1 replica block: inc [1, BLK_CJ, blk_l], rhs
     # [1, blk_l, LANES] -> out [1, BLK_CJ, LANES] i32 (shared by both
@@ -91,120 +79,6 @@ def _kernel_replicated(l_axis: int, inc_ref, rhs_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def clause_counts(
-    include: jax.Array,   # [CJ, L] int8/bool — flattened (class, clause) rows
-    literals: jax.Array,  # [L] bool
-    *,
-    interpret: bool,
-) -> tuple[jax.Array, jax.Array]:
-    """(violations [CJ] i32, n_included [CJ] i32) via one MXU matmul."""
-    cj, L = include.shape
-    cjp = -(-cj // BLK_CJ) * BLK_CJ
-    Lp, blk_l = _pad_l(L)
-
-    inc = jnp.zeros((cjp, Lp), dtype=jnp.int8).at[:cj, :L].set(
-        include.astype(jnp.int8)
-    )
-    # rhs col 0: ~literal (violation counter); col 1: ones (include counter).
-    lit = literals.astype(jnp.int8)
-    rhs = lane_plane([1 - lit, jnp.ones_like(lit)], jnp.int8, rows=Lp)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, 1),
-        grid=(cjp // BLK_CJ, Lp // blk_l),
-        in_specs=[
-            pl.BlockSpec((BLK_CJ, blk_l), lambda i, l: (i, l)),
-            pl.BlockSpec((blk_l, LANES), lambda i, l: (l, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLK_CJ, LANES), lambda i, l: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cjp, LANES), jnp.int32),
-        interpret=interpret,
-    )(inc, rhs)
-    return out[:cj, 0], out[:cj, 1]
-
-
-def clause_eval(
-    include: jax.Array,   # [C, J, L] bool (post-fault TA actions)
-    literals: jax.Array,  # [L] bool
-    *,
-    training: bool,
-    interpret: bool,
-) -> jax.Array:
-    """Kernel-backed clause outputs [C, J] bool (same contract as ref)."""
-    C, J, L = include.shape
-    viol, n_inc = clause_counts(
-        include.reshape(C * J, L), literals, interpret=interpret
-    )
-    fired = viol == 0
-    empty = n_inc == 0
-    out = jnp.where(empty, jnp.bool_(training), fired)
-    return out.reshape(C, J)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def clause_counts_batch(
-    include: jax.Array,   # [CJ, L] int8/bool — flattened (class, clause) rows
-    literals: jax.Array,  # [B, L] bool — one row per datapoint
-    *,
-    interpret: bool,
-) -> tuple[jax.Array, jax.Array]:
-    """(violations [CJ, B] i32, n_included [CJ] i32) via ONE MXU matmul.
-
-    The batch-first form of :func:`clause_counts`: rhs columns 0..B-1 carry
-    ``~literal_b`` (per-datapoint violation counters) and column B carries
-    ones (the include counter — datapoint-independent, so a single column
-    refines the [L, 2B] design down to [L, B+1]). The include bank streams
-    HBM->VMEM once per *batch*; the grid tiles the flattened
-    (class x clause) axis, the datapoint-column axis and the literal axis
-    (innermost, accumulated).
-    """
-    cj, L = include.shape
-    B = literals.shape[0]
-    cjp = -(-cj // BLK_CJ) * BLK_CJ
-    Lp, blk_l = _pad_l(L)
-    cols = B + 1
-    colsp = -(-cols // LANES) * LANES
-
-    inc = jnp.zeros((cjp, Lp), dtype=jnp.int8).at[:cj, :L].set(
-        include.astype(jnp.int8)
-    )
-    rhs = jnp.zeros((Lp, colsp), dtype=jnp.int8)
-    rhs = rhs.at[:L, :B].set((1 - literals.astype(jnp.int8)).T)
-    rhs = rhs.at[:L, B].set(1)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, 2),
-        grid=(cjp // BLK_CJ, colsp // LANES, Lp // blk_l),
-        in_specs=[
-            pl.BlockSpec((BLK_CJ, blk_l), lambda i, j, l: (i, l)),
-            pl.BlockSpec((blk_l, LANES), lambda i, j, l: (l, j)),
-        ],
-        out_specs=pl.BlockSpec((BLK_CJ, LANES), lambda i, j, l: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((cjp, colsp), jnp.int32),
-        interpret=interpret,
-    )(inc, rhs)
-    return out[:cj, :B], out[:cj, B]
-
-
-def clause_eval_batch(
-    include: jax.Array,   # [C, J, L] bool (post-fault TA actions)
-    literals: jax.Array,  # [B, L] bool
-    *,
-    training: bool,
-    interpret: bool,
-) -> jax.Array:
-    """Kernel-backed batch-first clause outputs [B, C, J] bool."""
-    C, J, L = include.shape
-    B = literals.shape[0]
-    viol, n_inc = clause_counts_batch(
-        include.reshape(C * J, L), literals, interpret=interpret
-    )
-    fired = (viol == 0).T.reshape(B, C, J)
-    empty = (n_inc == 0).reshape(C, J)
-    return jnp.where(empty[None], jnp.bool_(training), fired)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def clause_counts_replicated(
     include: jax.Array,   # [R, CJ, L] int8/bool — per-replica include banks
     literals: jax.Array,  # [D, L] bool — replica r reads row r % D
@@ -213,11 +87,12 @@ def clause_counts_replicated(
 ) -> tuple[jax.Array, jax.Array]:
     """(violations [R, CJ] i32, n_included [R, CJ] i32) in ONE kernel launch.
 
-    Replica-first form of :func:`clause_counts`: a grid over
-    (replica, clause-block, literal-block), each replica contracting its own
-    include bank against its data stream's literal row. The rhs BlockSpec
-    maps replica ``r`` to literal row ``r % D``, so a hyperparameter grid
-    sharing one ordering's data stream stores the rhs once per ordering.
+    A grid over (replica, clause-block, literal-block), each replica
+    contracting its own include bank against its data stream's literal row
+    (rhs column 0: ~literal, the violation counter; column 1: ones, the
+    include counter). The rhs BlockSpec maps replica ``r`` to literal row
+    ``r % D``, so a hyperparameter grid sharing one ordering's data stream
+    stores the rhs once per ordering.
     """
     R, cj, L = include.shape
     D = literals.shape[0]
@@ -273,10 +148,11 @@ def clause_counts_batch_replicated(
 ) -> tuple[jax.Array, jax.Array]:
     """(violations [R, CJ, B] i32, n_included [R, CJ] i32) in ONE launch.
 
-    The replica-first form of :func:`clause_counts_batch`: a 4-D grid over
-    (replica, clause-block, column-block, literal-block), each replica
-    contracting its own include bank against its data stream's [L, B+1]
-    rhs. The rhs BlockSpec maps replica ``r`` to stream ``r % D`` — the
+    A 4-D grid over (replica, clause-block, column-block, literal-block),
+    each replica contracting its own include bank against its data
+    stream's [L, B+1] rhs: columns 0..B-1 carry ``~literal_b`` (the
+    per-datapoint violation counters), column B ones (the include counter,
+    datapoint-independent, so one column serves the whole batch). The rhs BlockSpec maps replica ``r`` to stream ``r % D`` — the
     factored layout rule — so a hyperparameter grid sharing one ordering's
     batch stores the rhs once per ordering instead of gathering an R/D-fold
     tiled copy (the take+vmap formulation this replaced). This is the
@@ -330,8 +206,8 @@ def clause_eval_batch_replicated(
     One launch of :func:`clause_counts_batch_replicated` — the whole
     analysis / serving-inference plane of R machines rides a single kernel
     grid with the ``r % D`` rhs index map doing the data-stream factoring
-    (previously a per-replica gather + vmap of :func:`clause_eval_batch`).
-    Bit-identical to stacking ``clause_eval_batch(include[r],
+    (previously a per-replica gather + vmap of a single-machine kernel).
+    Bit-identical to stacking ``ref.clause_eval_loop(include[r],
     literals[r % D])`` per replica.
     """
     R, C, J, L = include.shape
@@ -376,19 +252,6 @@ def _pad_w(W: int) -> tuple[int, int]:
     return -(-W // blk) * blk, blk
 
 
-def _packed_kernel(w_axis: int, inc_ref, lit_ref, out_ref):
-    # inc: [BLK_CJ, blk_w] u32, lit: [BLK_B, blk_w] u32 -> accumulate
-    # [BLK_CJ, BLK_B] i32 violation partial sums over the word axis.
-    @pl.when(pl.program_id(w_axis) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    viol = inc_ref[...][:, None, :] & ~lit_ref[...][None, :, :]
-    out_ref[...] += jnp.sum(
-        jax.lax.population_count(viol).astype(jnp.int32), axis=-1
-    )
-
-
 def _packed_kernel_replicated(w_axis: int, inc_ref, lit_ref, out_ref):
     # Leading length-1 replica block, as in _kernel_replicated.
     @pl.when(pl.program_id(w_axis) == 0)
@@ -399,69 +262,6 @@ def _packed_kernel_replicated(w_axis: int, inc_ref, lit_ref, out_ref):
     out_ref[...] += jnp.sum(
         jax.lax.population_count(viol).astype(jnp.int32), axis=-1
     )[None]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def clause_counts_batch_packed(
-    include_packed: jax.Array,   # [CJ, W] uint32 — packed include rows
-    literals_packed: jax.Array,  # [B, W] uint32 — packed literal rows
-    *,
-    interpret: bool,
-) -> jax.Array:
-    """Violations [CJ, B] i32 via the word-tiled AND+popcount grid.
-
-    ``n_included`` has no per-datapoint dependence, so unlike the unpacked
-    kernels there is no ones-column trick to fold it into the same launch —
-    callers derive emptiness from the include words directly (cheap:
-    [CJ, W] is 32x smaller than the bool include bank).
-    """
-    cj, W = include_packed.shape
-    B = literals_packed.shape[0]
-    cjp = -(-cj // BLK_CJ) * BLK_CJ
-    Wp, blk_w = _pad_w(W)
-    Bp = -(-B // BLK_B) * BLK_B
-
-    inc = jnp.zeros((cjp, Wp), dtype=jnp.uint32).at[:cj, :W].set(
-        include_packed
-    )
-    lit = jnp.zeros((Bp, Wp), dtype=jnp.uint32).at[:B, :W].set(
-        literals_packed
-    )
-
-    out = pl.pallas_call(
-        functools.partial(_packed_kernel, 2),
-        grid=(cjp // BLK_CJ, Bp // BLK_B, Wp // blk_w),
-        in_specs=[
-            pl.BlockSpec((BLK_CJ, blk_w), lambda i, j, w: (i, w)),
-            pl.BlockSpec((BLK_B, blk_w), lambda i, j, w: (j, w)),
-        ],
-        out_specs=pl.BlockSpec((BLK_CJ, BLK_B), lambda i, j, w: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((cjp, Bp), jnp.int32),
-        interpret=interpret,
-    )(inc, lit)
-    return out[:cj, :B]
-
-
-def clause_eval_batch_packed(
-    include_packed: jax.Array,   # [C, J, W] uint32 (packed post-fault actions)
-    literals_packed: jax.Array,  # [B, W] uint32
-    *,
-    training: bool,
-    interpret: bool,
-) -> jax.Array:
-    """Kernel-backed packed batch clause outputs [B, C, J] bool.
-
-    Same contract as ``ref.clause_eval_batch_packed`` — and, through the
-    packing contract, bit-identical to the unpacked oracle.
-    """
-    C, J, W = include_packed.shape
-    B = literals_packed.shape[0]
-    viol = clause_counts_batch_packed(
-        include_packed.reshape(C * J, W), literals_packed, interpret=interpret
-    )
-    fired = (viol == 0).T.reshape(B, C, J)
-    empty = ~jnp.any(include_packed != 0, axis=-1)
-    return jnp.where(empty[None], jnp.bool_(training), fired)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -535,17 +335,6 @@ def clause_eval_batch_replicated_packed(
 # ---------------------------------------------------------------------------
 
 
-def clause_eval_batch_pruned(
-    include: jax.Array, sel: jax.Array, literals: jax.Array,
-    *, training: bool, interpret: bool,
-) -> jax.Array:
-    """[C, J, L] x sel [C, M] x [B, L] -> [B, C, M] (see ref twin)."""
-    return clause_eval_batch(
-        _ref.gather_include(include, sel), literals,
-        training=training, interpret=interpret,
-    )
-
-
 def clause_eval_batch_pruned_replicated(
     include: jax.Array, sel: jax.Array, literals: jax.Array,
     *, training: bool, interpret: bool,
@@ -553,17 +342,6 @@ def clause_eval_batch_pruned_replicated(
     """[R, C, J, L] x sel [R, C, M] x [D, B, L] -> [R, B, C, M]."""
     return clause_eval_batch_replicated(
         _ref.gather_include(include, sel), literals,
-        training=training, interpret=interpret,
-    )
-
-
-def clause_eval_batch_pruned_packed(
-    include_packed: jax.Array, sel: jax.Array, literals_packed: jax.Array,
-    *, training: bool, interpret: bool,
-) -> jax.Array:
-    """[C, J, W] u32 x sel [C, M] x [B, W] u32 -> [B, C, M]."""
-    return clause_eval_batch_packed(
-        _ref.gather_include(include_packed, sel), literals_packed,
         training=training, interpret=interpret,
     )
 

@@ -72,28 +72,6 @@ def _per_device(kernel, *operands, replicas: int | None = None):
     )(*operands)
 
 
-def clause_eval(
-    include: jax.Array, literals: jax.Array, *, training: bool,
-    interpret: bool,
-) -> jax.Array:
-    """[C, J, L] bool x [L] bool -> [C, J] bool (see ref.clause_eval)."""
-    return _per_device(
-        partial(_ce.clause_eval, training=training, interpret=interpret),
-        include, literals,
-    )
-
-
-def clause_eval_batch(
-    include: jax.Array, literals: jax.Array, *, training: bool,
-    interpret: bool,
-) -> jax.Array:
-    """[C, J, L] bool x [B, L] bool -> [B, C, J] bool (see ref.clause_eval_batch)."""
-    return _per_device(
-        partial(_ce.clause_eval_batch, training=training, interpret=interpret),
-        include, literals,
-    )
-
-
 def clause_eval_replicated(
     include: jax.Array, literals: jax.Array, *, training: bool,
     interpret: bool,
@@ -118,19 +96,6 @@ def clause_eval_batch_replicated(
     )
 
 
-def clause_eval_batch_packed(
-    include_packed: jax.Array, literals_packed: jax.Array, *, training: bool,
-    interpret: bool,
-) -> jax.Array:
-    """[C, J, W] u32 x [B, W] u32 -> [B, C, J] bool (see
-    ref.clause_eval_batch_packed)."""
-    return _per_device(
-        partial(_ce.clause_eval_batch_packed, training=training,
-                interpret=interpret),
-        include_packed, literals_packed,
-    )
-
-
 def clause_eval_batch_replicated_packed(
     include_packed: jax.Array, literals_packed: jax.Array, *, training: bool,
     interpret: bool,
@@ -141,20 +106,6 @@ def clause_eval_batch_replicated_packed(
         partial(_ce.clause_eval_batch_replicated_packed, training=training,
                 interpret=interpret),
         include_packed, literals_packed, replicas=include_packed.shape[0],
-    )
-
-
-def clause_eval_batch_pruned(
-    include: jax.Array, sel: jax.Array, literals: jax.Array, *,
-    training: bool, interpret: bool,
-) -> jax.Array:
-    """[C, J, L] x sel [C, M] x [B, L] -> [B, C, M] (see
-    ref.clause_eval_batch_pruned). The include bank compacts to the
-    selected clauses before launch, so the kernel grid shrinks with M."""
-    return _per_device(
-        partial(_ce.clause_eval_batch_pruned, training=training,
-                interpret=interpret),
-        include, sel, literals,
     )
 
 
@@ -171,19 +122,6 @@ def clause_eval_batch_pruned_replicated(
     )
 
 
-def clause_eval_batch_pruned_packed(
-    include_packed: jax.Array, sel: jax.Array, literals_packed: jax.Array,
-    *, training: bool, interpret: bool,
-) -> jax.Array:
-    """[C, J, W] u32 x sel [C, M] x [B, W] u32 -> [B, C, M] (see
-    ref.clause_eval_batch_pruned_packed)."""
-    return _per_device(
-        partial(_ce.clause_eval_batch_pruned_packed, training=training,
-                interpret=interpret),
-        include_packed, sel, literals_packed,
-    )
-
-
 def clause_eval_batch_pruned_replicated_packed(
     include_packed: jax.Array, sel: jax.Array, literals_packed: jax.Array,
     *, training: bool, interpret: bool,
@@ -196,39 +134,6 @@ def clause_eval_batch_pruned_replicated_packed(
         include_packed, sel, literals_packed,
         replicas=include_packed.shape[0],
     )
-
-
-def feedback_step(
-    ta_state: jax.Array,
-    literals: jax.Array,
-    clause_out: jax.Array,
-    type1_sel: jax.Array,
-    type2_sel: jax.Array,
-    u: jax.Array,
-    *,
-    s: jax.Array,
-    n_states: int,
-    s_policy: str,
-    boost_true_positive: bool,
-    interpret: bool,
-) -> jax.Array:
-    """Same contract as ref.feedback_step, backed by the fused Pallas kernel."""
-    C, J, L = ta_state.shape
-    s = jnp.asarray(s, dtype=jnp.float32)
-    p_strengthen = jnp.where(boost_true_positive, 1.0, (s - 1.0) / s)
-    p_erase = (1.0 / s) if s_policy == "standard" else (s - 1.0) / s
-    out = _per_device(
-        partial(_fb.feedback_plane, n_states=n_states, interpret=interpret),
-        ta_state.reshape(C * J, L),
-        literals,
-        clause_out.reshape(C * J),
-        type1_sel.reshape(C * J),
-        type2_sel.reshape(C * J),
-        u.reshape(C * J, L),
-        p_strengthen,
-        jnp.asarray(p_erase, dtype=jnp.float32),
-    )
-    return out.reshape(C, J, L)
 
 
 def feedback_step_replicated(

@@ -6,8 +6,8 @@ The unpacked datapath in this repo moves one int8/bool per literal — at
 MNIST width (f = 784, L = 1568) every clause pass streams ~1.5 KB per
 sample where 49 uint32 words carry the same information. This module
 defines the packed representation and the pack/unpack boundaries; the
-packed clause kernels (``ref.clause_eval_batch_packed`` and the
-word-tiled Pallas kernel in ``clause_eval.py``) evaluate clauses as
+packed clause kernels (``ref.clause_eval_batch_replicated_packed`` and
+the word-tiled Pallas kernel in ``clause_eval.py``) evaluate clauses as
 ``AND`` + ``popcount`` over these words.
 
 Layout rule (DESIGN.md §13):

@@ -2,7 +2,12 @@
 
 These are the semantic ground truth: the Pallas kernels in ``clause_eval.py``
 and ``feedback.py`` are asserted allclose against these across shape/dtype
-sweeps (see tests/test_kernels_*.py). They are also the default CPU backend.
+sweeps (see tests/test_kernels.py). They are also the default CPU backend.
+
+Every backend entry is replica-first; one machine is its R = 1 slice. The
+per-machine functions :func:`clause_eval`, :func:`clause_eval_loop` and
+:func:`feedback_step` are no backend entries: they define one machine, and
+the replicated entries are tested against them stacked per replica.
 """
 from __future__ import annotations
 
@@ -25,34 +30,6 @@ def clause_eval(include: jax.Array, literals: jax.Array, *, training: bool) -> j
     fired = jnp.all(match, axis=-1)
     empty = ~jnp.any(include, axis=-1)
     return jnp.where(empty, jnp.bool_(training), fired)
-
-
-def clause_eval_batch(
-    include: jax.Array, literals: jax.Array, *, training: bool
-) -> jax.Array:
-    """Batch-first clause eval: literals [B, L] -> [B, C, J].
-
-    One [B, L] x [L, CJ] matmul instead of a vmap of per-sample AND-reductions:
-    the include bank is the stationary GEMM operand, read once per *batch*,
-    and the reduction rides the platform's GEMM (MXU on TPU, vectorized GEMM
-    on CPU). Batch rows on the left so the [B, C, J] result needs no
-    transpose.
-
-        violations[b, cj] = sum_l (1 - literal[b, l]) * include[cj, l]
-        clause fires     <=> violations == 0
-        clause is empty  <=> n_included == 0
-
-    f32 accumulation is exact here (counts are integers <= L << 2^24), so the
-    result is bit-identical to stacking :func:`clause_eval` over rows.
-    """
-    C, J, L = include.shape
-    B = literals.shape[0]
-    inc = include.reshape(C * J, L).astype(jnp.float32)
-    neg = 1.0 - literals.astype(jnp.float32)              # [B, L] — row b = ~lit_b
-    violations = neg @ inc.T                              # [B, CJ]
-    fired = (violations == 0).reshape(B, C, J)
-    empty = ~jnp.any(include, axis=-1)                    # [C, J]
-    return jnp.where(empty[None], jnp.bool_(training), fired)
 
 
 def clause_eval_loop(
@@ -98,9 +75,16 @@ def clause_eval_batch_replicated(
     ``r % D``): the whole cross-validation sweep's accuracy analysis — all
     three per-cycle sets concatenated (``accuracy.analyze_sets_replicated``)
     — and the serving fleet's batched ``infer`` path are each a single
-    contraction on this entry. Violation counts are integers << 2^24, so f32
-    accumulation is exact and the result is bit-identical to stacking
-    :func:`clause_eval_batch` per replica.
+    contraction on this entry. The include bank is the stationary GEMM
+    operand, read once per *batch*:
+
+        violations[b, cj] = sum_l (1 - literal[b, l]) * include[cj, l]
+        clause fires     <=> violations == 0
+        clause is empty  <=> n_included == 0
+
+    Violation counts are integers << 2^24, so f32 accumulation is exact and
+    the result is bit-identical to stacking :func:`clause_eval_loop` per
+    replica.
     """
     R, C, J, L = include.shape
     D, B, _ = literals.shape
@@ -115,50 +99,26 @@ def clause_eval_batch_replicated(
     return out.reshape(R, B, C, J)
 
 
-def clause_eval_batch_packed(
+def clause_eval_batch_replicated_packed(
     include_packed: jax.Array, literals_packed: jax.Array, *, training: bool
 ) -> jax.Array:
-    """Bit-packed batch clause eval: the FPGA's AND-tree, word-at-a-time.
-
-    Args:
-      include_packed: [C, J, W] uint32 — include masks packed per
-        ``packing.pack_include`` (W = 2*ceil(f/32) words; tail bits ZERO).
-      literals_packed: [B, W] uint32 — literal rows packed per
-        ``packing.pack_literals`` (same two-half layout).
-      training: empty-clause convention, as in :func:`clause_eval_batch`.
-
-    Returns: [B, C, J] bool, bit-identical to the unpacked oracle on the
-    corresponding bool operands.
+    """Replica-first bit-packed batch eval — the FPGA's AND-tree,
+    word-at-a-time: include [R, C, J, W] uint32 (packed per
+    ``packing.pack_include``, W = 2*ceil(f/32) words, tail bits ZERO) x
+    literals [D, B, W] uint32 (``packing.pack_literals``) -> [R, B, C, J]
+    bool.
 
         violations[b, c, j] = sum_w popcount(include[c,j,w] & ~literal[b,w])
         clause fires      <=> violations == 0
         clause is empty   <=> sum_w popcount(include[c,j,w]) == 0
 
-    Tail safety: include tail bits are zero by the packing contract, so
-    ``include & ~literals`` is zero at every pad position even though the
-    complement sets the literal tail to ones — each per-word popcount equals
-    the unpacked per-word violation count exactly, and the sums match
-    bit-for-bit.
-    """
-    viol_words = include_packed[None] & ~literals_packed[:, None, None, :]
-    viol = jnp.sum(
-        jax.lax.population_count(viol_words).astype(jnp.int32), axis=-1
-    )                                                     # [B, C, J]
-    fired = viol == 0
-    empty = ~jnp.any(include_packed != 0, axis=-1)        # [C, J]
-    return jnp.where(empty[None], jnp.bool_(training), fired)
-
-
-def clause_eval_batch_replicated_packed(
-    include_packed: jax.Array, literals_packed: jax.Array, *, training: bool
-) -> jax.Array:
-    """Replica-first bit-packed batch eval: include [R, C, J, W] uint32 x
-    literals [D, B, W] uint32 -> [R, B, C, J] bool.
-
     Replica ``r`` reads literal batch ``r % D`` — the same factored layout
-    rule as :func:`clause_eval_batch_replicated`, on packed words. MUST be
-    bit-identical to stacking :func:`clause_eval_batch_packed` per replica,
-    and (via the packing contract) to the unpacked replicated oracle.
+    rule as :func:`clause_eval_batch_replicated`, on packed words, and MUST
+    be bit-identical to it on the unpacked operands. Tail safety: include
+    tail bits are zero by the packing contract, so ``include & ~literals``
+    is zero at every pad position even though the complement sets the
+    literal tail to ones — each per-word popcount equals the unpacked
+    per-word violation count exactly.
     """
     R, C, J, W = include_packed.shape
     D, B, _ = literals_packed.shape
@@ -191,26 +151,6 @@ def gather_include(include: jax.Array, sel: jax.Array) -> jax.Array:
     return jnp.take_along_axis(include, sel[..., None], axis=-2)
 
 
-def clause_eval_batch_pruned(
-    include: jax.Array, sel: jax.Array, literals: jax.Array, *, training: bool
-) -> jax.Array:
-    """Budgeted batch eval: only the selected clauses are contracted.
-
-    Args:
-      include: [C, J, L] bool — the FULL post-fault include bank.
-      sel: [C, M] int32 — clause indices (within J) to evaluate, per class.
-      literals: [B, L] bool.
-
-    Returns [B, C, M] bool: column m is clause ``sel[c, m]``'s output.
-    MUST equal ``clause_eval_batch(include, literals)[:, c, sel[c, m]]``
-    bit-for-bit — the compaction is a pure gather, so every selected
-    clause (including empty ones) keeps its full-bank semantics.
-    """
-    return clause_eval_batch(
-        gather_include(include, sel), literals, training=training
-    )
-
-
 def clause_eval_batch_pruned_replicated(
     include: jax.Array, sel: jax.Array, literals: jax.Array, *, training: bool
 ) -> jax.Array:
@@ -219,19 +159,6 @@ def clause_eval_batch_pruned_replicated(
     ``r % D`` and its OWN clause ranking ``sel[r]``)."""
     return clause_eval_batch_replicated(
         gather_include(include, sel), literals, training=training
-    )
-
-
-def clause_eval_batch_pruned_packed(
-    include_packed: jax.Array, sel: jax.Array, literals_packed: jax.Array,
-    *, training: bool,
-) -> jax.Array:
-    """Bit-packed budgeted eval: include [C, J, W] u32 x sel [C, M] x
-    literals [B, W] u32 -> [B, C, M]. The AND+popcount contraction runs
-    over M gathered words-rows per class instead of J."""
-    return clause_eval_batch_packed(
-        gather_include(include_packed, sel), literals_packed,
-        training=training,
     )
 
 

@@ -21,10 +21,9 @@ Everything that used to be two parallel APIs — ``OnlineSession`` /
 ``TMFleetAdaptManager`` (``[K]``) — is now a thin shim over this class;
 the K = 1 slice reproduces the scalar semantics bit for bit (pinned by
 tests/test_service.py against oracles transcribed from the pre-redesign
-implementations). K = 1 with scalar runtime ports additionally keeps the
-specialized single-machine drain body (`online._consume_many`; the
-replicated plane costs ~1.3x at R = 1, DESIGN.md §10), which the same
-parity suite pins bitwise against the replicated path.
+implementations). One machine is a fleet of one: K = 1 runs the same
+replicated drain, serve, analysis and offline-training bodies as every
+other K, with no single-machine fork (DESIGN.md §10).
 """
 from __future__ import annotations
 
@@ -374,14 +373,6 @@ class TMService:
         self.eval_x = None if eval_x is None else self._ingest(eval_x)
         self.eval_y = None if eval_y is None else jnp.asarray(eval_y,
                                                               jnp.int32)
-        # K = 1 with scalar runtime ports keeps the specialized
-        # single-machine drain/inference bodies (DESIGN.md §10: the
-        # replicated plane costs ~1.3x at R = 1); pinned bitwise against
-        # the replicated path by the parity suites.
-        # Patch machines take the replicated bodies at every K.
-        self._k1 = (K == 1 and self.mesh is None and not cfg.patched
-                    and jnp.ndim(self.rt.s) == 0 and jnp.ndim(self.rt.T) == 0)
-
         seed = sc.seed
         if isinstance(seed, (int, np.integer)):
             base = jax.random.PRNGKey(int(seed))
@@ -980,8 +971,7 @@ class TMService:
         """:meth:`drain`'s chunk loop, after the flush."""
         K = self.n_replicas
         if self._res is None:
-            return (self._drain_k1(budget, on_chunk) if self._k1
-                    else self._drain_replicated(budget, on_chunk))
+            return self._drain_replicated(budget, on_chunk)
         # Residency: sweep EVERY replica holding buffered rows (and
         # budget) in cohorts of <= resident slots — no lane starves
         # behind the working set, and sparse traffic only ever
@@ -1052,32 +1042,6 @@ class TMService:
             np.subtract.at(self._dev_size, self._res.replica_of[m],
                            n_plane[m])
 
-    def _drain_k1(self, budget, on_chunk) -> np.ndarray:
-        """The specialized single-machine drain body on the K = 1 slice."""
-        ss1 = jax.tree.map(lambda a: a[0], self._ss)
-        trained, budget1 = 0, int(budget[0])
-        monitor = on_chunk is not None
-        while trained < budget1:
-            want = min(self.chunk, budget1 - trained)
-            self._keys, chunk_keys = _advance_keys(
-                self._keys, jnp.ones((1,), bool)
-            )
-            ss1, n, aux = online_mod._consume_many(
-                self.cfg, self.chunk, ss1, self.rt,
-                jnp.int32(want), chunk_keys[0], monitor=monitor,
-            )
-            n = int(n)
-            trained += n
-            # commit state + mirror before the callback (see drain())
-            self._ss = jax.tree.map(lambda a: a[None], ss1)
-            with self.router.lock:
-                self._dev_size[0] -= n
-            if monitor and n:
-                on_chunk(jax.tree.map(lambda a: a[None], aux))
-            if n < want:  # buffer drained before the budget ran out
-                break
-        return np.asarray([trained], dtype=np.int64)
-
     # -- inference ----------------------------------------------------------
 
     def serve(self, xs, *, budget=None, return_aux: bool = False):
@@ -1122,11 +1086,6 @@ class TMService:
                         "return_aux reports the budgeted path's compute — "
                         "pass a budget (or configure an active tunable)"
                     )
-                if xs.ndim == 2 and self._k1:
-                    tm1 = jax.tree.map(lambda a: a[0], self._ss.tm)
-                    return np.asarray(
-                        tm_mod.predict_batch(self.cfg, tm1, self.rt, xs)
-                    )[None]
                 if xs.ndim == 2:
                     # D = 1: one shared stream, factored (stored once)
                     xs = xs[None]
@@ -1260,15 +1219,9 @@ class TMService:
         scores = np.zeros((K, C, J), dtype=np.int32)
         with self._device():
             if self._res is None:
-                if self._k1:
-                    tm1 = jax.tree.map(lambda a: a[0], self._ss.tm)
-                    scores[0] = np.asarray(tun_mod.clause_scores(
-                        self.cfg, tm1, self.rt, xs, ys
-                    ))
-                else:
-                    scores[:] = np.asarray(tun_mod.clause_scores_replicated(
-                        self.cfg, self._ss.tm, self.rt, xs[None], ys[None]
-                    ))
+                scores[:] = np.asarray(tun_mod.clause_scores_replicated(
+                    self.cfg, self._ss.tm, self.rt, xs[None], ys[None]
+                ))
             else:
                 for i in range(0, K, self.n_resident):
                     cohort = np.arange(i, min(i + self.n_resident, K))
@@ -1310,12 +1263,6 @@ class TMService:
     def _measure(self) -> np.ndarray:
         """One eval contraction over the device plane; [K] f32 (nan for
         evicted replicas). No history side effects."""
-        if self._k1:
-            tm1 = jax.tree.map(lambda a: a[0], self._ss.tm)
-            # same [K] f32 contract as the K > 1 path
-            return np.asarray([float(acc_mod.analyze(
-                self.cfg, tm1, self.rt, self.eval_x, self.eval_y
-            ))], dtype=np.float32)
         acc_p = np.asarray(acc_mod.analyze_replicated(
             self.cfg, self._ss.tm, self.rt,
             self.eval_x[None], self.eval_y[None],  # D = 1: shared
@@ -1346,18 +1293,10 @@ class TMService:
             return self._offline_train_locked(xs, ys, n_epochs, seed)
 
     def _offline_train_locked(self, xs, ys, n_epochs, seed) -> np.ndarray:
-        if self._k1:
-            tm1 = jax.tree.map(lambda a: a[0], self._ss.tm)
-            st = fb_mod.train_epochs(
-                self.cfg, tm1, self.rt, xs, ys,
-                jax.random.PRNGKey(seed), n_epochs,
-            )
-            st = jax.tree.map(lambda a: a[None], st)
-        else:
-            st = fb_mod.train_epochs_replicated(
-                self.cfg, self._ss.tm, self.rt, xs[None], ys[None],
-                jax.random.PRNGKey(seed)[None], n_epochs,
-            )
+        st = fb_mod.train_epochs_replicated(
+            self.cfg, self._ss.tm, self.rt, xs[None], ys[None],
+            jax.random.PRNGKey(seed)[None], n_epochs,
+        )
         self._ss = self._ss._replace(tm=st)
         acc = self.analyze()
         self.policy.snapshot(self._ps, acc, st)

@@ -1,7 +1,8 @@
 """Batch-first kernel path + backend dispatch layer.
 
-The batch contract (DESIGN.md §8): ``clause_eval_batch(include, lits_B)``
-must equal stacking the per-sample kernel over rows bit-for-bit, on every
+The batch contract (DESIGN.md §8): ``clause_eval_batch_replicated`` at one
+machine (R = D = 1, as the core's single-machine functions call it) must
+equal stacking the per-sample kernel over rows bit-for-bit, on every
 backend, for every shape — including the awkward ones (B=1, B=257, L not a
 multiple of the 128-lane tile).
 """
@@ -31,6 +32,12 @@ BATCH_SHAPES = [
 ]
 
 
+def _batch_of_one(kb, include, lits, training):
+    """The batch entry at one machine: [C, J, L] x [B, L] -> [B, C, J]."""
+    return kb.clause_eval_batch_replicated(
+        include[None], lits[None], training=training)[0]
+
+
 def _rand_case(shape, seed=None):
     C, J, L, B = shape
     rng = np.random.default_rng(seed if seed is not None else hash(shape) % 2**31)
@@ -46,7 +53,7 @@ def test_clause_eval_batch_matches_per_sample_loop(shape, training):
     want = ref.clause_eval_loop(include, lits, training=training)
     for backend in ("ref", "pallas"):
         kb = dispatch.resolve(backend)
-        got = kb.clause_eval_batch(include, lits, training=training)
+        got = _batch_of_one(kb, include, lits, training)
         np.testing.assert_array_equal(
             np.asarray(want), np.asarray(got), err_msg=f"backend={backend}"
         )
@@ -56,10 +63,8 @@ def test_clause_eval_batch_matches_per_sample_loop(shape, training):
 def test_clause_eval_batch_ref_pallas_bit_parity(shape):
     include, lits = _rand_case(shape, seed=11)
     for training in (True, False):
-        a = ref.clause_eval_batch(include, lits, training=training)
-        b = dispatch.resolve("pallas").clause_eval_batch(
-            include, lits, training=training
-        )
+        a = _batch_of_one(dispatch.resolve("ref"), include, lits, training)
+        b = _batch_of_one(dispatch.resolve("pallas"), include, lits, training)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -68,8 +73,8 @@ def test_clause_eval_batch_empty_clause_convention():
     lits = jnp.asarray(np.random.default_rng(0).random((5, 32)) < 0.5)
     for backend in ("ref", "pallas"):
         kb = dispatch.resolve(backend)
-        assert bool(jnp.all(kb.clause_eval_batch(include, lits, training=True)))
-        assert not bool(jnp.any(kb.clause_eval_batch(include, lits, training=False)))
+        assert bool(jnp.all(_batch_of_one(kb, include, lits, True)))
+        assert not bool(jnp.any(_batch_of_one(kb, include, lits, False)))
 
 
 def test_dispatch_registry_names_and_auto():
@@ -148,8 +153,13 @@ def test_analyze_matches_per_sample_predictions():
     assert abs(got - want) < 1e-6
 
 
+def _fleet_of_one(tree):
+    return jax.tree.map(lambda a: jnp.asarray(a)[None], tree)
+
+
 def test_consume_many_matches_serial_updates():
-    """_consume_many == a hand loop of train_update over the same keys."""
+    """The drain at one machine (_consume_many_replicated, R = 1) == a hand
+    loop of train_update over the same keys."""
     from repro.core import feedback as fb_mod
     from repro.data import buffer as buf_mod
     from repro.data import iris
@@ -165,11 +175,13 @@ def test_consume_many_matches_serial_updates():
             buf, jnp.asarray(xs[i], dtype=bool), jnp.int32(ys[i])
         )
         assert bool(ok)
-    ss = online_mod.SessionState(tm=st, buf=buf, step=jnp.int32(0))
+    ss = _fleet_of_one(
+        online_mod.SessionState(tm=st, buf=buf, step=jnp.int32(0)))
 
     key = jax.random.PRNGKey(9)
-    out, n, aux = online_mod._consume_many(cfg, K, ss, rt, jnp.int32(K), key)
-    assert int(n) == K and int(out.buf.size) == 0
+    out, n, aux = online_mod._consume_many_replicated(
+        cfg, K, ss, rt, jnp.full((1,), K, jnp.int32), key[None])
+    assert n.shape == (1,) and int(n[0]) == K and int(out.buf.size[0]) == 0
 
     ref_tm = st
     for i, kk in enumerate(jax.random.split(key, K)):
@@ -178,9 +190,9 @@ def test_consume_many_matches_serial_updates():
             jnp.int32(ys[i]), kk
         )
     np.testing.assert_array_equal(
-        np.asarray(out.tm.ta_state), np.asarray(ref_tm.ta_state)
+        np.asarray(out.tm.ta_state[0]), np.asarray(ref_tm.ta_state)
     )
-    assert aux.valid.shape == (K,) and bool(jnp.all(aux.valid))
+    assert aux.valid.shape == (1, K) and bool(jnp.all(aux.valid))
 
 
 def test_consume_many_respects_limit_and_empty_buffer():
@@ -196,18 +208,20 @@ def test_consume_many_respects_limit_and_empty_buffer():
         buf, _ = buf_mod.push(
             buf, jnp.asarray(xs[i], dtype=bool), jnp.int32(ys[i])
         )
-    ss = online_mod.SessionState(tm=st, buf=buf, step=jnp.int32(0))
-    key = jax.random.PRNGKey(10)
+    ss = _fleet_of_one(
+        online_mod.SessionState(tm=st, buf=buf, step=jnp.int32(0)))
+    key = jax.random.PRNGKey(10)[None]
 
     # limit < buffered: stops at the limit, leaves the rest buffered
-    out, n, _ = online_mod._consume_many(cfg, 8, ss, rt, jnp.int32(3), key)
-    assert int(n) == 3 and int(out.buf.size) == 2
+    out, n, _ = online_mod._consume_many_replicated(
+        cfg, 8, ss, rt, jnp.full((1,), 3, jnp.int32), key)
+    assert int(n[0]) == 3 and int(out.buf.size[0]) == 2
     # chunk > buffered: consumes what exists, TM state untouched afterwards
-    out2, n2, aux2 = online_mod._consume_many(
-        cfg, 8, out, rt, jnp.int32(8), key
+    out2, n2, aux2 = online_mod._consume_many_replicated(
+        cfg, 8, out, rt, jnp.full((1,), 8, jnp.int32), key
     )
-    assert int(n2) == 2 and int(out2.buf.size) == 0
-    assert not bool(jnp.any(aux2.valid[2:]))
+    assert int(n2[0]) == 2 and int(out2.buf.size[0]) == 0
+    assert not bool(jnp.any(aux2.valid[0, 2:]))
 
 
 def test_online_session_chunked_learn_counts():

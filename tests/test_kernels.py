@@ -1,6 +1,9 @@
 """Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracle.
 
 Shape/dtype sweeps + bit-exactness, per the kernel contract in DESIGN.md §8.
+One machine is the R = D = 1 slice of the replicated entries; the
+per-machine oracles are ``ref.clause_eval``, ``ref.clause_eval_loop`` and
+``ref.feedback_step``.
 """
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,19 @@ SHAPES = [
 ]
 
 
+def _clause_eval_one(kb, include, lits, training):
+    """The clause entry at one machine: [C, J, L] x [L] -> [C, J]."""
+    return kb.clause_eval_replicated(include[None], lits[None],
+                                     training=training)[0]
+
+
+def _feedback_one(kb, ta, lits, c_out, t1, t2, u, *, s, **kw):
+    """The feedback entry at one machine: [C, J, L] -> [C, J, L]."""
+    return kb.feedback_step_replicated(
+        ta[None], lits[None], c_out[None], t1[None], t2[None], u[None],
+        s=jnp.reshape(s, (1,)), **kw)[0]
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("training", [True, False])
 def test_clause_eval_matches_ref(shape, training):
@@ -30,8 +46,8 @@ def test_clause_eval_matches_ref(shape, training):
     include = jnp.asarray(rng.random((C, J, L)) < 0.3)
     lits = jnp.asarray(rng.random((L,)) < 0.5)
     want = ref.clause_eval(include, lits, training=training)
-    got = dispatch.resolve("pallas").clause_eval(include, lits,
-                                                 training=training)
+    got = _clause_eval_one(dispatch.resolve("pallas"), include, lits,
+                           training)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -39,15 +55,15 @@ def test_clause_eval_all_excluded_is_empty():
     include = jnp.zeros((2, 4, 32), dtype=bool)
     lits = jnp.ones((32,), dtype=bool)
     kb = dispatch.resolve("pallas")
-    assert bool(jnp.all(kb.clause_eval(include, lits, training=True)))
-    assert not bool(jnp.any(kb.clause_eval(include, lits, training=False)))
+    assert bool(jnp.all(_clause_eval_one(kb, include, lits, True)))
+    assert not bool(jnp.any(_clause_eval_one(kb, include, lits, False)))
 
 
 def test_clause_eval_single_violation_kills_clause():
     L = 32
     include = jnp.zeros((1, 2, L), dtype=bool).at[0, 0, 7].set(True)
     lits = jnp.ones((L,), dtype=bool).at[7].set(False)
-    out = dispatch.resolve("pallas").clause_eval(include, lits, training=True)
+    out = _clause_eval_one(dispatch.resolve("pallas"), include, lits, True)
     assert not bool(out[0, 0])  # included literal is 0 -> clause 0
     assert bool(out[0, 1])      # empty clause in training -> 1
 
@@ -69,8 +85,8 @@ def test_feedback_matches_ref(shape, policy, dtype):
         kw = dict(s=jnp.float32(1.375), n_states=n_states, s_policy=policy,
                   boost_true_positive=boost)
         want = ref.feedback_step(ta, lits, c_out, t1, t2, u, **kw)
-        got = dispatch.resolve("pallas").feedback_step(
-            ta, lits, c_out, t1, t2, u, **kw)
+        got = _feedback_one(dispatch.resolve("pallas"),
+                            ta, lits, c_out, t1, t2, u, **kw)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -81,9 +97,9 @@ def test_feedback_states_stay_in_bounds():
     lits = jnp.ones((L,), dtype=bool)
     ones = jnp.ones((C, J), dtype=bool)
     u = jnp.zeros((C, J, L), dtype=jnp.float32)  # every draw fires
-    out = dispatch.resolve("pallas").feedback_step(
-        ta, lits, ones, ones, jnp.zeros_like(ones), u,
-        s=jnp.float32(1.0), n_states=n, s_policy="standard",
+    out = _feedback_one(
+        dispatch.resolve("pallas"), ta, lits, ones, ones,
+        jnp.zeros_like(ones), u, s=jnp.float32(1.0), n_states=n, s_policy="standard",
         boost_true_positive=True,
     )
     o = np.asarray(out)
@@ -108,8 +124,9 @@ def test_clause_eval_batch_packed_matches_unpacked_oracle(shape, mod):
     inc_p = packing.pack_bits(include)
     lit_p = packing.pack_bits(lits)
     for training in (True, False):
-        want = ref.clause_eval_batch(include, lits, training=training)
-        got = mod.clause_eval_batch_packed(inc_p, lit_p, training=training)
+        want = ref.clause_eval_loop(include, lits, training=training)
+        got = mod.clause_eval_batch_replicated_packed(
+            inc_p[None], lit_p[None], training=training)[0]
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -163,7 +180,7 @@ def test_clause_eval_batch_replicated_matches_stacked(shape, mod):
     lits = jnp.asarray(rng.random((D, 5, L)) < 0.5)
     for training in (True, False):
         want = jnp.stack([
-            ref.clause_eval_batch(include[r], lits[r % D], training=training)
+            ref.clause_eval_loop(include[r], lits[r % D], training=training)
             for r in range(R)
         ])
         got = mod.clause_eval_batch_replicated(include, lits, training=training)

@@ -283,10 +283,6 @@ def test_jitted_entries_carry_their_scope():
     assert obs_mod.DRAIN in _lowered(
         online_mod._consume_many_replicated, svc.cfg, CHUNK, ss, svc.rt,
         want, keys, monitor=False)
-    ss1 = jax.tree.map(lambda a: a[0], ss)
-    assert obs_mod.DRAIN in _lowered(
-        online_mod._consume_many, svc.cfg, CHUNK, ss1, svc.rt,
-        jnp.int32(CHUNK), keys[0], monitor=False)
     assert obs_mod.FLUSH in _lowered(
         service_mod._activate_enqueue_rows, ss, keys, BLOCK,
         np.zeros(K, bool), ss, keys, xs, ys, counts)

@@ -126,6 +126,13 @@ def test_pack_include_matches_literal_positions(f):
 # ---------------------------------------------------------------------------
 
 
+def _packed_one(kb, inc_p, lit_p, training):
+    """The packed batch entry at one machine: [C, J, W] x [B, W] ->
+    [B, C, J]."""
+    return kb.clause_eval_batch_replicated_packed(
+        inc_p[None], lit_p[None], training=training)[0]
+
+
 def _case(f, seed, C=3, J=6, B=17):
     rng = np.random.default_rng(seed)
     include = jnp.asarray(rng.random((C, J, 2 * f)) < 0.3)
@@ -141,8 +148,8 @@ def test_clause_eval_batch_packed_matches_unpacked(f, mod):
     mod = dispatch.resolve(mod)
     include, lits, inc_p, lit_p = _case(f, seed=f)
     for training in (True, False):
-        want = ref.clause_eval_batch(include, lits, training=training)
-        got = mod.clause_eval_batch_packed(inc_p, lit_p, training=training)
+        want = ref.clause_eval_loop(include, lits, training=training)
+        got = _packed_one(mod, inc_p, lit_p, training)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -158,12 +165,10 @@ def test_clause_eval_batch_packed_empty_and_all_include(mod):
     for inc in (inc_empty, inc_full):
         inc_p = packing.pack_include(inc, f)
         for training in (True, False):
-            want = ref.clause_eval_batch(
+            want = ref.clause_eval_loop(
                 inc, jnp.concatenate([x, ~x], axis=-1), training=training
             )
-            got = mod.clause_eval_batch_packed(
-                inc_p, lit_p, training=training
-            )
+            got = _packed_one(mod, inc_p, lit_p, training)
             np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 

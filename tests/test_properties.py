@@ -28,8 +28,8 @@ def test_kernel_clause_eval_equals_oracle(shape, seed, training):
     include = jnp.asarray(rng.random((C, J, L)) < rng.random())
     lits = jnp.asarray(rng.random((L,)) < 0.5)
     want = ref.clause_eval(include, lits, training=training)
-    got = dispatch.resolve("pallas").clause_eval(include, lits,
-                                                 training=training)
+    got = dispatch.resolve("pallas").clause_eval_replicated(
+        include[None], lits[None], training=training)[0]
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -50,11 +50,13 @@ def test_kernel_feedback_equals_oracle_and_bounds(shape, seed, s, policy):
     t1 = jnp.asarray(rng.random((C, J)) < 0.5)
     t2 = jnp.asarray(rng.random((C, J)) < 0.5) & ~t1
     u = jnp.asarray(rng.random((C, J, L)), dtype=jnp.float32)
-    kw = dict(s=jnp.float32(s), n_states=n, s_policy=policy,
-              boost_true_positive=bool(seed % 2))
-    want = np.asarray(ref.feedback_step(ta, lits, c_out, t1, t2, u, **kw))
-    got = np.asarray(dispatch.resolve("pallas").feedback_step(
-        ta, lits, c_out, t1, t2, u, **kw))
+    kw = dict(n_states=n, s_policy=policy, boost_true_positive=bool(seed % 2))
+    want = np.asarray(ref.feedback_step(ta, lits, c_out, t1, t2, u,
+                                        s=jnp.float32(s), **kw))
+    # the fused kernel at one machine (R = D = 1)
+    got = np.asarray(dispatch.resolve("pallas").feedback_step_replicated(
+        ta[None], lits[None], c_out[None], t1[None], t2[None], u[None],
+        s=jnp.full((1,), s, jnp.float32), **kw)[0])
     np.testing.assert_array_equal(want, got)
     # Invariants: states in [1, 2N]; |delta| <= 1 per TA per step.
     assert want.min() >= 1 and want.max() <= 2 * n

@@ -28,6 +28,7 @@ from repro.core.online import OnlineSession, SessionState
 from repro.core.tm import TMState
 from repro.data import buffer as buf_mod
 from repro.data import iris
+from repro.kernels import packing
 from repro.serve.fleet import OnlineFleet
 from repro.serve.online_adapt import (
     TMFleetAdaptManager,
@@ -73,6 +74,41 @@ def _legacy_enqueue_rows(cfg, ss, xs, ys, mask):
     return ss._replace(buf=bufs), oks
 
 
+@partial(jax.jit, static_argnums=(0, 1), static_argnames=("monitor",))
+def _legacy_consume_many(cfg, k, ss, rt, limit, key, *, monitor=True):
+    """The pre-redesign single-machine drain: up to ``min(k, limit,
+    buffered)`` rows in one jitted scan of ``train_update``, monitoring
+    hoisted into one batched inference pass over the chunk."""
+    packed = ss.buf.data_x.dtype == jnp.uint32
+
+    def body(carry, inp):
+        buf, tm, n = carry
+        i, kk = inp
+        new_buf, x, y, nonempty = buf_mod.pop(buf)
+        valid = (i < limit) & nonempty
+        xb = packing.unpack_bits(x, cfg.n_features) if packed else x
+        new_tm, _, activity = fb_mod.train_update(cfg, tm, rt, xb, y, kk)
+        tm = jax.tree.map(lambda a, b: jnp.where(valid, a, b), new_tm, tm)
+        buf = jax.tree.map(lambda a, b: jnp.where(valid, a, b), new_buf, buf)
+        n = n + valid.astype(jnp.int32)
+        return (buf, tm, n), (x, y, valid, jnp.where(valid, activity, 0.0))
+
+    idx = jnp.arange(k, dtype=jnp.int32)
+    (buf, tm, n), (xs, ys, valids, activity) = jax.lax.scan(
+        body, (ss.buf, ss.tm, jnp.int32(0)), (idx, jax.random.split(key, k))
+    )
+    aux = None
+    if monitor:
+        preds = tm_mod.predict_batch_(cfg, tm, rt, xs)
+        aux = online_mod.ChunkAux(
+            predicted=preds.astype(jnp.int32),
+            correct=(preds == ys) & valids,
+            valid=valids,
+            activity=activity,
+        )
+    return SessionState(tm=tm, buf=buf, step=ss.step + n), n, aux
+
+
 @jax.jit
 def _legacy_advance_keys(keys, active):
     k2 = jax.vmap(jax.random.split)(keys)
@@ -110,7 +146,7 @@ class LegacySession:
         while trained < max_points:
             want = min(self.chunk, max_points - trained)
             self._key, k = jax.random.split(self._key)
-            self.ss, n, aux = online_mod._consume_many(
+            self.ss, n, aux = _legacy_consume_many(
                 self.cfg, self.chunk, self.ss, self.rt, jnp.int32(want), k,
                 monitor=monitor,
             )

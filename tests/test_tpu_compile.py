@@ -1,7 +1,7 @@
 """Compiles for a described TPU v5e chip that is not attached.
 
-The Pallas kernels of the main path and the packed fleet drain compile at
-the f=784 preset (``configs/tm_mnist.py``: 10 classes x 64 clauses x 1568
+The Pallas kernels of the main path, one machine's calls of them (the
+R = 1 grids) and the packed fleet drain compile at the f=784 preset (``configs/tm_mnist.py``: 10 classes x 64 clauses x 1568
 literals) with interpret mode off, for one chip and for a 2x2 mesh; the
 patch entries at the Convolutional TM's widths (361 patches of 272
 literals, 10 x 2,000 clauses). The
@@ -23,8 +23,10 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import tm_mnist
+from repro.core import feedback as fb_mod
 from repro.core import init_runtime
 from repro.core import online as online_mod
+from repro.core import tm as tm_mod
 from repro.core.online import SessionState
 from repro.core.tm import TMConfig, TMState
 from repro.data import buffer as buf_mod
@@ -96,24 +98,21 @@ def _entry_operands(name: str):
         return ([(bank, jnp.bool_),
                  ((R, 1, CTM.n_patches, CTM.n_literals), jnp.bool_),
                  ((R, 1) + bank[1:3], jnp.float32)], dict(training=True))
-    rep = "replicated" in name
     packed = name.endswith("packed")
-    inc = ((R,) if rep else ()) + (C, J) + ((W,) if packed else (L,))
+    inc = (R, C, J) + ((W,) if packed else (L,))
     inc_dt = jnp.uint32 if packed else jnp.bool_
     if name.startswith("feedback"):
-        lead = (R,) if rep else ()
-        ops = [(lead + (C, J, L), CFG.state_dtype), (lead + (L,), jnp.bool_),
-               (lead + (C, J), jnp.bool_), (lead + (C, J), jnp.bool_),
-               (lead + (C, J), jnp.bool_), (lead + (C, J, L), jnp.float32),
-               (lead, jnp.float32)]
+        ops = [((R, C, J, L), CFG.state_dtype), ((R, L), jnp.bool_),
+               ((R, C, J), jnp.bool_), ((R, C, J), jnp.bool_),
+               ((R, C, J), jnp.bool_), ((R, C, J, L), jnp.float32),
+               ((R,), jnp.float32)]
         return ops, dict(n_states=CFG.n_states, s_policy=CFG.s_policy,
                          boost_true_positive=CFG.boost_true_positive)
-    lit = ((R,) if rep else ()) + (() if name == "clause_eval_replicated"
-                                   else (B,) if name != "clause_eval" else ())
+    lit = (R,) if name == "clause_eval_replicated" else (R, B)
     lit += (W,) if packed else (L,)
     ops = [(inc, inc_dt)]
     if "pruned" in name:
-        ops.append((((R,) if rep else ()) + (C, M), jnp.int32))
+        ops.append(((R, C, M), jnp.int32))
     ops.append((lit, inc_dt))
     return ops, dict(training=False)
 
@@ -129,6 +128,54 @@ def test_kernel_entry_compiles_at_f784(topo, pallas, name):
         f = partial(fn, **kw)
     args = [_sds(s, d, one_chip) for s, d in ops]
     compiled = jax.jit(f).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# One machine's core functions, each now the R = D = 1 call of a replicated
+# entry: (function of (runtime, operands...), operand shapes+dtypes).
+_F, _WF = CFG.n_features, packing.n_words(CFG.n_features)
+_BANK = ((C, J, L), CFG.state_dtype)
+_SEL = ((C, M), jnp.int32)
+SINGLE_MACHINE_CALLS = {
+    "eval_clauses": (
+        lambda rt, inc, lit: tm_mod.eval_clauses(CFG, inc, lit, rt,
+                                                 training=True),
+        [((C, J, L), jnp.bool_), ((L,), jnp.bool_)]),
+    "eval_clauses_batch": (
+        lambda rt, inc, lit: tm_mod.eval_clauses_batch(CFG, inc, lit, rt,
+                                                       training=False),
+        [((C, J, L), jnp.bool_), ((B, L), jnp.bool_)]),
+    "eval_clauses_batch_packed": (
+        lambda rt, inc, lit: tm_mod.eval_clauses_batch_packed(
+            CFG, inc, lit, rt, training=False),
+        [((C, J, W), jnp.uint32), ((B, W), jnp.uint32)]),
+    "forward_batch_pruned": (
+        lambda rt, ta, xs, sel: tm_mod.forward_batch_pruned(
+            CFG, TMState(ta_state=ta), rt, xs, sel)[1],
+        [_BANK, ((B, _F), jnp.bool_), _SEL]),
+    "forward_batch_pruned_packed": (
+        lambda rt, ta, xs, sel: tm_mod.forward_batch_pruned(
+            CFG, TMState(ta_state=ta), rt, xs, sel)[1],
+        [_BANK, ((B, _WF), jnp.uint32), _SEL]),
+    "train_update": (
+        lambda rt, ta, x, y, key: fb_mod.train_update(
+            CFG, TMState(ta_state=ta), rt, x, y, key)[0].ta_state,
+        [_BANK, ((_F,), jnp.bool_), ((), jnp.int32), ((2,), jnp.uint32)]),
+}
+
+
+@pytest.mark.parametrize("name", SINGLE_MACHINE_CALLS)
+def test_single_machine_call_compiles_at_f784(topo, pallas, name):
+    """A single machine runs the replicated kernel grids at R = D = 1, and
+    every single-machine caller relies on that grid compiling for the
+    chip: clause eval, batch, batch packed, both pruned entries (through
+    ``forward_batch_pruned``) and feedback (through ``train_update``)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    fn, ops = SINGLE_MACHINE_CALLS[name]
+    rt = jax.eval_shape(lambda: init_runtime(CFG, s=1.5, T=32))
+    rt = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), rt)
+    args = [_sds(s, d, one_chip) for s, d in ops]
+    compiled = jax.jit(fn).lower(rt, *args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
