@@ -84,7 +84,8 @@ class KernelBackend(NamedTuple):
       clause_out [R,C,J], type1_sel [R,C,J], type2_sel [R,C,J], u [D,C,J,L],
       *, s [R], n_states, s_policy, boost_true_positive) -> [R,C,J,L]`` —
       R independent TA-bank updates in one fused plane (ref: one [R, C·J, L]
-      elementwise pass; pallas: a (replica, clause-block) grid); MUST
+      elementwise pass; pallas: a grid of tenant blocks, or of
+      (replica, clause-block, literal-block) tiles at RB = 1); MUST
       equal stacking ``ref.feedback_step`` per replica bit-for-bit.
 
     Patch machines (the Convolutional TM, DESIGN.md §18) have two entries of
@@ -159,6 +160,18 @@ def resolve(name: str) -> KernelBackend:
     if name not in _CACHE:
         _CACHE[name] = _FACTORIES[name]()
     return _CACHE[name]
+
+
+def feedback_grid_steps(name: str, R: int, cj: int, L: int, dtype,
+                        mesh=None) -> int:
+    """Grid steps the backend's ``feedback_step_replicated`` launches for R
+    banks of [cj, L] with a stream each, over ``mesh``'s devices (see
+    ``ops.feedback_grid_steps``); 0 for a backend with no kernel grid."""
+    if resolve(name).name != "pallas":
+        return 0
+    from repro.kernels import ops
+
+    return ops.feedback_grid_steps(R, cj, L, dtype, mesh)
 
 
 def _make_ref() -> KernelBackend:

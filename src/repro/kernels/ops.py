@@ -35,6 +35,14 @@ def _shard_streams(x: jax.Array, local: int) -> jax.Array:
     return jnp.take(x, (off + jnp.arange(local)) % x.shape[0], axis=0)
 
 
+def _split(mesh, replicas: int | None) -> tuple[bool, int]:
+    """(whether ``replicas`` split over the mesh's :data:`REPLICA_AXIS`,
+    replicas per device when they do)."""
+    n = dict(mesh.shape).get(REPLICA_AXIS, 1)
+    split = replicas is not None and n > 1 and replicas % n == 0
+    return split, replicas // n if split else 0
+
+
 def _per_device(kernel, *operands, replicas: int | None = None):
     """Run ``kernel(*operands)`` on each device of the context mesh.
 
@@ -48,9 +56,7 @@ def _per_device(kernel, *operands, replicas: int | None = None):
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return kernel(*operands)
-    n = dict(mesh.shape).get(REPLICA_AXIS, 1)
-    split = replicas is not None and n > 1 and replicas % n == 0
-    local = replicas // n if split else 0
+    split, local = _split(mesh, replicas)
 
     def sharded(x) -> bool:
         return split and jnp.ndim(x) > 0 and x.shape[0] == replicas
@@ -151,7 +157,7 @@ def feedback_step_replicated(
     interpret: bool,
 ) -> jax.Array:
     """Same contract as ref.feedback_step_replicated: R TA banks, ONE launch
-    of the 2-D-grid (replica, clause-block) fused Pallas plane."""
+    of the fused Pallas plane (its grid: ``feedback.tenant_block``)."""
     R, C, J, L = ta_state.shape
     D = literals.shape[0]
     s = jnp.broadcast_to(jnp.asarray(s, dtype=jnp.float32), (R,))
@@ -171,6 +177,18 @@ def feedback_step_replicated(
         replicas=R,
     )
     return out.reshape(R, C, J, L)
+
+
+def feedback_grid_steps(R: int, cj: int, L: int, dtype, mesh=None) -> int:
+    """Grid steps of one :func:`feedback_step_replicated` call over R banks
+    of [cj, L] with a data stream each (D = R, the drain's plane), summed
+    over the devices of ``mesh`` (None: one device) as :func:`_per_device`
+    runs it there."""
+    if mesh is None or mesh.size == 1:
+        return _fb.grid_steps(R, R, cj, L, dtype)
+    split, local = _split(mesh, R)
+    local = local if split else R
+    return mesh.size * _fb.grid_steps(local, local, cj, L, dtype)
 
 
 def patch_clause_eval_replicated(

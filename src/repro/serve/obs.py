@@ -23,7 +23,10 @@ One small system, three parts:
   ``flush.rows`` (rows landed in device buffers), ``flush.slots`` (plane
   length x ingress block per enqueue dispatch: the staging slots it
   carries, filled or not), ``residency.activations`` /
-  ``residency.evictions``, ``patch.evals.<pass>`` (a patch machine's row
+  ``residency.evictions``, ``feedback.grid_steps`` (a plain machine's
+  drain: grid steps of the Pallas feedback kernel per dispatch, from the
+  plane's shapes: one launch per scan step on each device; 0 on the
+  ``ref`` backend), ``patch.evals.<pass>`` (a patch machine's row
   slots x patches put through patch evaluation, counted at dispatch from
   the planes' shapes, for the ``train`` and ``monitor`` passes of the
   drain and the ``analysis`` pass), and the process-wide ``jit.traces`` /
@@ -56,6 +59,9 @@ POLICY = "tm.policy"
 # through patch evaluation, one key per pass
 PATCH_EVALS = "patch.evals"
 PATCH_PASSES = ("train", "monitor", "analysis")
+# a plain machine's counter: grid steps of the drain's feedback kernel
+# (kernels/feedback.py), from the plane's shapes, per drain dispatch
+FEEDBACK_GRID_STEPS = "feedback.grid_steps"
 
 # spans and jaxpr-trace times kept in memory; the oldest drop first (a
 # traced benchmark window records a few dozen spans a second)
@@ -184,6 +190,7 @@ class Obs:
         self._counts = {"flush.rows": 0, "flush.slots": 0,
                         "residency.activations": 0,
                         "residency.evictions": 0,
+                        FEEDBACK_GRID_STEPS: 0,
                         **{f"{PATCH_EVALS}.{p}": 0 for p in PATCH_PASSES}}
 
     def span(self, name: str):

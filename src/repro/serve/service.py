@@ -46,7 +46,7 @@ from repro.core.online import ChunkAux, SessionState
 from repro.core.tm import TMConfig, TMRuntime, TMState, init_runtime
 from repro.data import buffer as buf_mod
 from repro.distributed import sharding as shard_mod
-from repro.kernels import packing
+from repro.kernels import dispatch, packing
 from repro.serve import obs as obs_mod
 from repro.serve import residency as res_mod
 from repro.serve import router as router_mod
@@ -1014,6 +1014,7 @@ class TMService:
                 jnp.asarray(want), chunk_keys, monitor=monitor,
             )
             self._count_patch_evals("train", K * self.chunk)
+            self._count_feedback_steps(K)
             if monitor:
                 self._count_patch_evals("monitor", K * self.chunk)
             n = np.asarray(n, dtype=np.int64)
@@ -1031,6 +1032,18 @@ class TMService:
         if self.cfg.patched:
             self.obs.add(f"{obs_mod.PATCH_EVALS}.{pass_}",
                          row_slots * self.cfg.n_patches)
+
+    def _count_feedback_steps(self, plane: int) -> None:
+        """``feedback.grid_steps``: the feedback kernel's grid steps in a
+        drain dispatch of ``plane`` tenants, one launch per scan step
+        (plain machines only)."""
+        cfg = self.cfg
+        if not cfg.patched:
+            self.obs.add(obs_mod.FEEDBACK_GRID_STEPS,
+                         self.chunk * dispatch.feedback_grid_steps(
+                             cfg.backend, plane,
+                             cfg.max_classes * cfg.max_clauses,
+                             cfg.n_literals, cfg.state_dtype, self.mesh))
 
     def _debit_mirror(self, n_plane) -> None:
         """Map a device-plane consumed-rows vector onto the [K] mirror

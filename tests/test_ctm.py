@@ -232,6 +232,7 @@ def test_two_drained_chunks_equal_reference(backend, threshold):
     assert c["patch.evals.train"] == 2 * K * 4 * 81
     assert c["patch.evals.analysis"] == 2 * K * 6 * 81
     assert c["patch.evals.monitor"] == 0
+    assert c["feedback.grid_steps"] == 0      # the patch kernel is not counted
 
 
 def test_bfloat16_uniforms_change_the_replay():

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import clause_eval, dispatch, packing, ref
+from repro.kernels import clause_eval, dispatch, feedback, ops, packing, ref
 
 SHAPES = [
     (1, 2, 5),      # degenerate
@@ -207,13 +207,20 @@ def test_clause_eval_batch_replicated_packed_matches_unpacked(shape, mod):
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
-@pytest.mark.parametrize("shape", REP_SHAPES)
+# the iris machine at 64 tenants: a stream per tenant and per 4 tenants
+# take the kernel's tenant-blocked grid, one stream shared by all (a
+# cross-validation grid) the tiled one
+IRIS_R64 = [(64, 64, 3, 16, 32), (64, 16, 3, 16, 32), (64, 1, 3, 16, 32)]
+
+
+@pytest.mark.parametrize("shape", REP_SHAPES + IRIS_R64)
 @pytest.mark.parametrize("policy", ["standard", "hardware"])
 @pytest.mark.parametrize("dtype", [jnp.int8, jnp.int16])
 @pytest.mark.parametrize("mod", ["ref", "pallas"])
 def test_feedback_replicated_matches_stacked(shape, policy, dtype, mod):
     """feedback_step_replicated == stacking per-replica feedback_step calls,
-    bit for bit, on both backends (pallas in interpret mode)."""
+    bit for bit, on both backends (pallas in interpret mode), on either of
+    the Pallas kernel's grids."""
     mod = dispatch.resolve(mod)
     R, D, C, J, L = shape
     n_states = 50 if dtype == jnp.int8 else 5000
@@ -236,6 +243,44 @@ def test_feedback_replicated_matches_stacked(shape, policy, dtype, mod):
             ta, lits, c_out, t1, t2, u, s=s, **kw
         )
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.int16])
+def test_feedback_tenant_block_rule(dtype):
+    """RB, the tenants a feedback grid step updates, from shapes alone: a
+    power of two dividing R and D, blocks inside the VMEM budget; the iris
+    cell's plane takes at most 128 steps, a shared stream (D = 1) and the
+    f=784 preset's width keep RB = 1 and the tiled grid's step count."""
+    iris = (48, 32, dtype)
+    rb = feedback.tenant_block(4096, 4096, *iris)
+    assert rb > 1 and feedback.grid_steps(4096, 4096, *iris) <= 128
+    assert feedback.tenant_block(64, 64, *iris) > 1
+    assert feedback.tenant_block(64, 16, *iris) == 16
+    assert feedback.tenant_block(4096, 1, *iris) == 1
+    assert feedback.grid_steps(64, 1, *iris) == 64 * 2      # 2 clause tiles
+    f784 = (640, 1568, dtype)                 # 10 x 64 clauses, 1568 literals
+    assert feedback.tenant_block(256, 256, *f784) == 1
+    assert feedback.grid_steps(256, 256, *f784) == 256 * 20 * 4
+    for R in (1, 2, 6, 64, 96, 4096):
+        for D in (d for d in (1, 2, 3, 16, 32, 64, 4096) if R % d == 0):
+            for cj, L in ((48, 32), (132, 129), (640, 1568), (2, 5)):
+                rb = feedback.tenant_block(R, D, cj, L, dtype)
+                assert rb & (rb - 1) == 0 and R % rb == 0 and D % rb == 0
+
+
+def test_feedback_grid_steps_sum_over_a_mesh():
+    """``feedback.grid_steps`` on a mesh: the replicas split over the
+    replica axis when it divides them, each device launching its shard's
+    grid; otherwise every device launches the whole plane's."""
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
+    iris = (48, 32, jnp.int8)
+    assert (ops.feedback_grid_steps(4096, *iris, mesh)
+            == 4 * feedback.grid_steps(1024, 1024, *iris) == 4 * 32)
+    assert (ops.feedback_grid_steps(6, *iris, mesh)
+            == 4 * feedback.grid_steps(6, 6, *iris))
+    assert (ops.feedback_grid_steps(4096, *iris)
+            == dispatch.feedback_grid_steps("pallas", 4096, *iris) == 128)
+    assert dispatch.feedback_grid_steps("ref", 4096, *iris) == 0
 
 
 def _column_scatter_plane(cols, dtype, rows):

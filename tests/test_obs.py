@@ -14,6 +14,7 @@ import pytest
 
 from repro.core import TMConfig, init_state
 from repro.core import online as online_mod
+from repro.kernels import feedback
 from repro.serve import AdaptPolicy, ServiceConfig, TMService
 from repro.serve import obs as obs_mod
 from repro.serve import router as router_mod
@@ -28,19 +29,19 @@ ROWS_X = _RNG.random((64, K, F)) > 0.5
 ROWS_Y = _RNG.integers(0, 3, (64, K))
 
 
-def _cfg():
+def _cfg(backend="ref"):
     return TMConfig(n_features=F, max_classes=3, max_clauses=16,
-                    n_states=16, backend="ref")
+                    n_states=16, backend=backend)
 
 
-def _service(replicas=K, **kw):
+def _service(replicas=K, backend="ref", **kw):
     sc = ServiceConfig(
         replicas=replicas, buffer_capacity=CAP, chunk=CHUNK,
         ingress_block=BLOCK, s=3.0, T=15, seed=5,
         policy=AdaptPolicy(analyze_every=CHUNK, rollback_threshold=0.1),
         **kw)
-    return TMService(_cfg(), init_state(_cfg()), sc, eval_x=EVAL_X,
-                     eval_y=EVAL_Y)
+    cfg = _cfg(backend)
+    return TMService(cfg, init_state(cfg), sc, eval_x=EVAL_X, eval_y=EVAL_Y)
 
 
 class _Profiler:
@@ -194,6 +195,33 @@ def test_flush_counters(resident):
     c1 = svc.obs.counters()
     assert c1["flush.rows"] - c0["flush.rows"] == landed
     assert c1["flush.slots"] - c0["flush.slots"] == dispatches * plane * BLOCK
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_feedback_grid_steps_counter(backend, monkeypatch):
+    """``feedback.grid_steps``: the Pallas feedback kernel's grid steps per
+    drain dispatch, one launch per scan step, from the plane's shapes. K
+    tenants with a stream each fit one tenant-blocked step (the tiled grid
+    took two 32-clause tiles each); the ref backend has no grid."""
+    dispatches = []
+    drain = online_mod._consume_many_replicated
+
+    def counted(*a, **kw):
+        dispatches.append(1)
+        return drain(*a, **kw)
+
+    monkeypatch.setattr(online_mod, "_consume_many_replicated", counted)
+    svc = _service(backend=backend)
+    c0 = svc.obs.counters()["feedback.grid_steps"]
+    for i in range(2 * CHUNK):
+        _submit(svc, i)
+        if i % CHUNK == CHUNK - 1:
+            svc.tick()
+    per_launch = feedback.grid_steps(K, K, 3 * 16, 2 * F, jnp.int8)
+    assert per_launch == 1
+    assert len(dispatches) >= 2
+    assert (svc.obs.counters()["feedback.grid_steps"] - c0
+            == len(dispatches) * CHUNK * per_launch * (backend == "pallas"))
 
 
 def test_residency_counters_cover_repartition():

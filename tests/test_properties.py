@@ -7,7 +7,7 @@ import pytest
 pytest.importorskip(
     "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
 )
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from repro.core import TMConfig, init_runtime, init_state, train_step
 from repro.core import tm as tm_mod
@@ -249,13 +249,18 @@ def test_block_orderings_partition_dataset(
     seed=st.integers(0, 2**31 - 1),
     shape=st.tuples(
         st.integers(1, 3),                        # H (grid cells per stream)
-        st.integers(1, 3),                        # D (data streams)
+        st.integers(1, 8),                        # D (data streams)
         st.integers(1, 3),                        # classes
         st.integers(1, 6).map(lambda j: 2 * j),   # clauses (even)
         st.integers(1, 40),                       # literals
     ),
     policy=st.sampled_from(["standard", "hardware"]),
 )
+# the iris machine at R = 64 with 64, 16 and 1 streams: the Pallas kernel's
+# tenant-blocked grid (RB = 32, 16) and its tiled one (RB = 1)
+@example(seed=64, shape=(1, 64, 3, 16, 32), policy="standard")
+@example(seed=16, shape=(4, 16, 3, 16, 32), policy="hardware")
+@example(seed=1, shape=(64, 1, 3, 16, 32), policy="standard")
 def test_kernel_feedback_replicated_equals_stacked_oracle(seed, shape, policy):
     """Property form of the replica parity contract: for any R = H*D layout,
     feedback_step_replicated == stacked per-replica feedback_step, bitwise,

@@ -31,7 +31,7 @@ from repro.core.online import SessionState
 from repro.core.tm import TMConfig, TMState
 from repro.data import buffer as buf_mod
 from repro.distributed import sharding as shard_mod
-from repro.kernels import dispatch, packing
+from repro.kernels import dispatch, feedback, packing
 
 CFG = tm_mnist.CONFIG.tm
 C, J, L = CFG.max_classes, CFG.max_clauses, CFG.n_literals
@@ -240,15 +240,25 @@ def _while_body_ops(hlo: str) -> list[str]:
 IRIS = TMConfig(n_features=16, max_classes=3, max_clauses=16, n_states=16)
 
 
-@pytest.mark.parametrize("cfg", [IRIS, CFG], ids=["iris", "f784"])
+# temporaries of the iris cell's drain (K = 4096) on a described v5e while
+# the feedback kernel still took padded 32x128 tiles: the blocked kernel's
+# drain holds its arguments and temporaries within that
+IRIS_DRAIN_BYTES = 228_069_888
+
+
+@pytest.mark.parametrize("cfg,k", [(IRIS, 4096), (CFG, 64)],
+                         ids=["iris", "f784"])
 def test_drain_step_builds_kernel_control_planes_without_scatters(
-        topo, pallas, cfg):
+        topo, pallas, cfg, k):
     """The scan step's control planes (feedback ``ctl`` and ``p``, clause
     count ``rhs``) are dense writes: no dynamic-update-slice or scatter in
-    the drain's loop comes from the two kernels' operand preparation."""
+    the drain's loop comes from the two kernels' operand preparation. At
+    the iris cell's 4,096 tenants the feedback kernel takes the unpadded
+    banks in tenant blocks (no padded copy in or out) and the drain fits
+    in the bytes it held before."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     compiled = _compile_drain(_drain_args(
-        lambda t: jax.tree.map(lambda _: one_chip, t), cfg=cfg, k=64), cfg=cfg)
+        lambda t: jax.tree.map(lambda _: one_chip, t), cfg=cfg, k=k), cfg=cfg)
     body = _while_body_ops(compiled.as_text())
     kernels = ("feedback_plane_replicated", "clause_counts_replicated")
     assert any("custom-call(" in op and any(k in op for k in kernels)
@@ -257,6 +267,16 @@ def test_drain_step_builds_kernel_control_planes_without_scatters(
            if re.search(r" (dynamic-update-slice|scatter)\(", op)
            and any(f"jit({k})" in op for k in kernels)]
     assert not bad, bad
+    if cfg is IRIS:
+        cj = cfg.max_classes * cfg.max_clauses
+        assert feedback.tenant_block(k, k, cj, cfg.n_literals,
+                                     cfg.state_dtype) > 1
+        bank = f"s8[{k},{cj},{cfg.n_literals}]"
+        assert any(re.search(rf"= {re.escape(bank)}\S* custom-call\(", op)
+                   and "feedback_plane_replicated" in op for op in body)
+        mem = compiled.memory_analysis()
+        used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert used <= IRIS_DRAIN_BYTES, used
 
 
 def test_packed_drain_k256_compiles_and_fits_one_chip(topo, pallas):
